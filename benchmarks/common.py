@@ -7,9 +7,10 @@ the structure DALI exploits — random-init models route near-uniformly and
 show none of the paper's dynamics).  Trained params and traces are cached
 under reports/bench_cache/.
 
-Timing comes from the calibrated cost model (paper hardware profile);
-prefetch accuracy / cache hit rate / cosine similarity are measured
-quantities (see DESIGN.md §2).
+Modeled step times come from the cost model of the device the run is on
+(``cost_model.DEVICE_PROFILES``: the paper's RTX 3090 platform on the
+CPU backend) — estimates, not measurements; prefetch accuracy / cache
+hit rate / cosine similarity are measured quantities (see DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ import numpy as np
 
 from repro.checkpoint.store import restore, save
 from repro.configs import get_config, make_smoke
-from repro.core.cost_model import CostModel, LOCAL_PC
+from repro.core.cost_model import CostModel
 from repro.core.prefetch import (FeaturePrefetcher, RandomPrefetcher,
                                  ResidualPrefetcher, StatisticalPrefetcher)
 from repro.core.residual import calibrate_residuals
@@ -43,11 +44,13 @@ CACHE_DIR = os.path.normpath(os.path.join(
 # --------------------------------------------------------------------------
 
 # name -> (module under benchmarks/, entry attr).  "run" entries are the
-# legacy Csv-collector suites, imported in-process; "main" entries are
-# CLI benchmarks (argparse, --smoke preset, machine-readable
-# reports/bench/*.json) which the runner executes in a SUBPROCESS — they
-# may need their own XLA environment (ep_exchange forces an 8-device host
-# platform, which cannot be changed once jax is initialised in-process).
+# legacy Csv-collector suites; "main" entries are CLI benchmarks
+# (argparse, --smoke preset, machine-readable reports/bench/*.json) whose
+# ``main(argv)`` the runner calls with ``["--smoke"]``.  Both run in the
+# runner's own process: a parent that has touched JAX holds the chip, so
+# a child could not reach it.  ep_exchange needs several devices — on a
+# CPU host, start the runner with
+# XLA_FLAGS=--xla_force_host_platform_device_count=8.
 SUITE_SPECS = {
     "speed": ("speed_vs_frameworks", "run"),        # Figs 12, 13
     "prefetch_acc": ("prefetch_accuracy", "run"),   # Table 2, Fig 16b
@@ -67,18 +70,14 @@ SUITE_SPECS = {
 
 
 def load_suite(name: str):
-    """Resolve a registered suite to a ``fn(csv)`` callable."""
+    """Resolve a registered suite to a ``fn(csv)`` callable, run in this
+    process."""
     import importlib
-    import subprocess
-    import sys
     mod_name, attr = SUITE_SPECS[name]
+    entry = getattr(importlib.import_module(f"benchmarks.{mod_name}"), attr)
     if attr == "main":
-        def run_cli(csv, _mod=mod_name):
-            subprocess.run(
-                [sys.executable, "-m", f"benchmarks.{_mod}", "--smoke"],
-                check=True)
-        return run_cli
-    return getattr(importlib.import_module(f"benchmarks.{mod_name}"), attr)
+        return lambda csv: entry(["--smoke"])
+    return entry
 
 # the paper's evaluation models (Table 3), reduced same-family
 BENCH_MODELS = ["mixtral-8x7b", "deepseek-v2-lite-16b", "qwen3-30b-a3b"]
@@ -166,7 +165,7 @@ def load_model(arch: str, train_steps: int = 150, seed: int = 0,
     res_vecs = calibrate_residuals([calib])
     bm = BenchModel(arch=arch, cfg=cfg, params=params, corpus=corpus,
                     res_vecs=res_vecs, gate_ws=gate_weights(params, cfg),
-                    cost=CostModel.for_config(get_config(arch), LOCAL_PC))
+                    cost=CostModel.for_config(get_config(arch)))
     _MODELS[key] = bm
     return bm
 

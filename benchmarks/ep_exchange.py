@@ -1,6 +1,8 @@
 """Expert-parallel exchange: workload-sized ragged all_to_all vs the
 dense full-capacity exchange (models/moe_ep.py, DESIGN.md §6), on a
-host-platform 8-device mesh.
+(1, n) mesh over every device JAX sees — the chips of a TPU host, or
+host-platform CPU devices forced from outside with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 The dense path ships every (E/tp, C, d) capacity bucket through BOTH
 all_to_alls regardless of how empty it is; the ragged path exchanges
@@ -16,21 +18,17 @@ Zipf(1.2)-skewed routing ships the hot expert's rung.
   PYTHONPATH=src python -m benchmarks.ep_exchange            # full sweep
   PYTHONPATH=src python -m benchmarks.ep_exchange --smoke    # CI tiers
 
+It runs on whatever platform JAX selects; JAX_PLATFORMS and the forced
+host-device count come from the caller's environment, never from here.
+
 Emits the ``name,us_per_call,derived`` CSV contract on stdout and a
 machine-readable ``reports/bench/BENCH_ep_exchange.json`` (rendered into
 EXPERIMENTS.md by benchmarks/report_md.py)."""
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
 import argparse
 import json
+import os
 from typing import Dict, List
 
 import jax
@@ -39,6 +37,7 @@ import numpy as np
 
 from benchmarks.common import time_fn
 from repro.launch import sharding as shd
+from repro.launch.mesh import ep_mesh
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.moe import apply_moe, init_moe
 from repro.models.moe_ep import ep_applicable
@@ -134,7 +133,7 @@ def bench_one(kind: str, dtype: str, B: int, S: int, reps: int,
     }
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced shapes + reps for CI")
@@ -155,11 +154,11 @@ def main():
     ap.add_argument("--json", default=None,
                     help="output path (default reports/bench/"
                          "BENCH_ep_exchange.json)")
-    args = ap.parse_args()
-    if len(jax.devices()) < 8:
-        raise SystemExit("ep_exchange needs 8 devices (host-platform "
-                         "forced; run as a fresh process)")
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
+    mesh = ep_mesh(E)
+    tp = mesh.shape["model"]
     B, S = (4, 160) if args.smoke else (8, 320)
     dtypes = ("float32",) if args.smoke else ("float32", "bfloat16")
     reps = args.reps or (5 if args.smoke else 20)
@@ -209,7 +208,7 @@ def main():
     out = args.json or os.path.join(BENCH_DIR, "BENCH_ep_exchange.json")
     os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
     with open(out, "w") as f:
-        json.dump({"backend": jax.default_backend(), "tp": 8,
+        json.dump({"backend": jax.default_backend(), "tp": tp,
                    "E": E, "top_k": K, "d_model": D_MODEL,
                    "d_expert": D_EXPERT, "smoke": bool(args.smoke),
                    "count_overlap": bool(args.overlap),

@@ -63,7 +63,7 @@ def bench_one(E: int, B: int, reps: int) -> Dict:
     }
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sweep + reps for CI tier-2")
@@ -71,7 +71,9 @@ def main():
     ap.add_argument("--json", default=None,
                     help="output path (default reports/bench/"
                          "BENCH_moe_dispatch.json)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     experts = (8, 64) if args.smoke else EXPERT_COUNTS
     batches = (1, 4) if args.smoke else BATCHES
     reps = args.reps or (5 if args.smoke else 30)

@@ -233,7 +233,7 @@ def run_prefill_modes(params, cfg, pol, *, batch: int, prompt_len: int,
     caches0 = init_caches(cfg, batch, max_len)
 
     ref_fn = jax.jit(make_prefill_step(cfg, max_len))
-    ref_tok, ref_caches = jax.block_until_ready(ref_fn(params, toks,
+    ref_tok, ref_caches, _ = jax.block_until_ready(ref_fn(params, toks,
                                                        caches0))
     ref_leaves = jax.tree_util.tree_leaves(ref_caches)
 
@@ -273,7 +273,7 @@ def run_prefill_modes(params, cfg, pol, *, batch: int, prompt_len: int,
             state = rs.init_state(batch=batch, max_len=max_len)
             snap = dict(rs.store.stats())
             t0 = time.perf_counter()
-            tok, caches = jax.block_until_ready(
+            tok, caches, _ = jax.block_until_ready(
                 fn(p, toks, caches0, None, state["offload"]))
             walls[m].append(time.perf_counter() - t0)
             now = rs.store.stats()
@@ -495,6 +495,8 @@ def main(argv=None):
                     help="reduced steps/training for CI tier-2 (recorded "
                          "in the JSON)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     picked = [m.strip() for m in args.offload.split(",") if m.strip()]
     bad = [m for m in picked if m not in MODES]
     if bad:

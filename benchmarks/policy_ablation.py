@@ -122,6 +122,8 @@ def main(argv=None):
                          "tier-2 (recorded in the JSON so a smoke row is "
                          "never diffed against a full run)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     if args.smoke:
         args.steps = min(args.steps, 10)
     reps = args.reps or (5 if args.smoke else 20)

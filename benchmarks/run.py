@@ -23,6 +23,8 @@ def main() -> None:
                     help="comma-separated suite names "
                          f"(registered: {','.join(SUITE_SPECS)})")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     picks = args.only.split(",") if args.only else list(SUITE_SPECS)
     unknown = [p for p in picks if p not in SUITE_SPECS]
     if unknown:

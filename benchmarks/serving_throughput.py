@@ -98,7 +98,7 @@ def run_server(kind: str, bm, workload, batch: int, max_len: int,
     }
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
     ap.add_argument("--requests", type=int, default=24)
@@ -120,7 +120,9 @@ def main():
                          "smoke row is never diffed against a full run)")
     ap.add_argument("--json", default=None,
                     help="output path (default reports/serving/<arch>.json)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     if args.smoke:
         args.requests = min(args.requests, 8)
         args.max_prompt = min(args.max_prompt, 24)

@@ -50,7 +50,8 @@ except ImportError:                     # pragma: no cover
 _CALLBACK_PRIMS = {"pure_callback": "pure", "io_callback": "io"}
 #: host-sync primitives that are NOT seam callbacks: a stray
 #: ``jax.debug.print`` lowers to one of these and stalls every step
-_SYNC_PRIMS = ("debug_callback",)
+#: (JAX 0.9 traces it as its own ``debug_print`` primitive)
+_SYNC_PRIMS = ("debug_callback", "debug_print")
 
 
 # --------------------------------------------------------------------------
@@ -116,9 +117,11 @@ def callback_census(closed: ClosedJaxpr) -> List[CallbackSite]:
         if kind is None:
             if name in _SYNC_PRIMS:
                 target = _callback_target(eqn)
-                sites.append(CallbackSite(kind="debug", guarded=guarded,
-                                          target=_target_name(target),
-                                          seam=None))
+                sites.append(CallbackSite(
+                    kind="debug", guarded=guarded,
+                    target=(_target_name(target) if target is not None
+                            else f"{name}({eqn.params.get('fmt', '')!r})"),
+                    seam=None))
             continue
         target = _callback_target(eqn)
         sites.append(CallbackSite(kind=kind, guarded=guarded,
@@ -175,9 +178,9 @@ def audit_entry(ep: EntryPoint) -> Dict[str, Any]:
                 n_unguarded += 1
                 violations.append(Violation(
                     E_SYNC_CENSUS, ep.name,
-                    f"unconditional host sync: debug_callback "
-                    f"({s.target}) runs every step — drop the "
-                    f"jax.debug.print or guard it under lax.cond"))
+                    f"unconditional host sync: {s.target} runs every "
+                    f"step — drop the jax.debug.print or guard it "
+                    f"under lax.cond"))
             continue
         if s.seam is None:
             violations.append(Violation(

@@ -65,6 +65,32 @@ TPU_V5E_HOST = HardwareProfile(
 
 PROFILES = {p.name: p for p in (LOCAL_PC, TPU_V5E_HOST)}
 
+# The profile that prices each device JAX reports, keyed by its
+# ``device_kind``.  The CPU backend keeps the paper's platform (the CPU
+# tests are written against it).  A device not listed here is an error,
+# never a silent default: pricing a TPU with PCIe-3090 constants would
+# mis-size every cache, prefetch and assignment decision.
+DEVICE_PROFILES = {
+    "cpu": LOCAL_PC,
+    "TPU v5 lite": TPU_V5E_HOST,
+    "TPU v5e": TPU_V5E_HOST,
+}
+
+
+def profile_for_device(device=None) -> HardwareProfile:
+    """The :data:`DEVICE_PROFILES` entry for ``device`` (default: JAX's
+    first device); raises ``ValueError`` for a kind the table lacks."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind
+    try:
+        return DEVICE_PROFILES[kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost profile for device kind {kind!r}; add one to "
+            f"DEVICE_PROFILES (known: {sorted(DEVICE_PROFILES)})") from None
+
 
 class TopologyParseError(ValueError):
     """Malformed ``--topology`` spec (typed so callers can catch it)."""
@@ -174,7 +200,7 @@ _TOPO_PAIR_RE = re.compile(
 
 
 def parse_topology(spec, n_devices: int,
-                   profile: HardwareProfile = LOCAL_PC) -> LinkTopology:
+                   profile: HardwareProfile | None = None) -> LinkTopology:
     """Parse a ``--topology`` spec string into a :class:`LinkTopology`.
 
     Grammar (comma-separated; first item is the base, rest are
@@ -187,12 +213,15 @@ def parse_topology(spec, n_devices: int,
     e.g. ``island:4,0>5:x8`` — two 4-device islands with the directed
     0->5 link 8x slower.  ``None``/empty -> homogeneous at the hardware
     profile's link constants.  Already-built topologies pass through.
-    Malformed specs raise :class:`TopologyParseError`.
+    Malformed specs raise :class:`TopologyParseError`.  ``profile``
+    defaults to the running device's (:func:`profile_for_device`).
     """
-    if spec is None or isinstance(spec, LinkTopology):
-        return spec if spec is not None else LinkTopology.homogeneous(
-            n_devices, profile.link_gbps, profile.link_latency_s)
-    items = [s.strip() for s in str(spec).split(",") if s.strip()]
+    if isinstance(spec, LinkTopology):
+        return spec
+    if profile is None:
+        profile = profile_for_device()
+    items = ([] if spec is None else
+             [s.strip() for s in str(spec).split(",") if s.strip()])
     if not items:
         return LinkTopology.homogeneous(
             n_devices, profile.link_gbps, profile.link_latency_s)
@@ -353,7 +382,11 @@ class CostModel:
 
     @classmethod
     def for_config(cls, cfg: ModelConfig,
-                   profile: HardwareProfile = LOCAL_PC) -> "CostModel":
+                   profile: HardwareProfile | None = None) -> "CostModel":
+        """Cost model of ``cfg``'s experts on ``profile`` (default: the
+        profile of the device JAX runs on, :func:`profile_for_device`)."""
+        if profile is None:
+            profile = profile_for_device()
         if cfg.moe is None:
             raise ValueError("cost model applies to MoE layers "
                              "(cfg.moe is None)")

@@ -18,6 +18,14 @@ Block sizes default to MXU-friendly multiples of 128 and are clamped to
 the problem size.  All matmuls accumulate in f32
 (preferred_element_type), output cast to the input dtype.
 
+Scoped VMEM: every block above is double-buffered, so at Mixtral widths
+(d 4096, bf 512, bf16) the kernel needs ~33 MiB, over the compiler's
+16 MiB default scoped limit.  The limit is raised to the blocks' own
+footprint (``_vmem_limit_bytes``) rather than shrinking bf: the weights
+stream from HBM once per (e, ci) whatever bf is, so smaller blocks only
+add grid steps, and the f-block accumulation order (hence the numerics)
+stays what the interpret-mode tests pin.  A TPU v5e core has 128 MiB.
+
 The ragged variant takes per-expert token ``counts`` (the MoE workload
 vector) via scalar prefetch and guards each (e, ci) block with ``pl.when``
 so capacity blocks holding no real tokens skip their MXU work entirely
@@ -100,6 +108,20 @@ def _sublane(dtype) -> int:
         jnp.dtype(dtype), 8)
 
 
+_MIB = 1 << 20
+
+
+def _vmem_limit_bytes(bc: int, bf: int, d: int, itemsize: int) -> int:
+    """Scoped-VMEM limit for one grid step: the double-buffered x, Wg,
+    Wu, Wd and f32 output blocks plus the step's f32 intermediates (the
+    two (bc, bf) projections and the (bc, d) down-projection partial),
+    with 25% headroom, never below the compiler's 16 MiB default."""
+    blocks = 2 * (bc * d * itemsize + 3 * d * bf * itemsize + bc * d * 4)
+    scratch = 3 * bc * bf * 4 + bc * d * 4
+    need = -(-(blocks + scratch) * 5 // 4 // _MIB) * _MIB
+    return max(16 * _MIB, need)
+
+
 def _block_size(n: int, target: int, unit: int = 1) -> int:
     """Largest divisor of n that is <= target and a multiple of ``unit``
     (the sublane tile), so arbitrary problem shapes — capacities pad to
@@ -148,6 +170,8 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
     bf = _block_size(f, block_f, sub)
     grid = (E, C // bc, f // bf)
     out_shape = jax.ShapeDtypeStruct((E, C, d), jnp.float32)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit_bytes(
+        bc, bf, d, jnp.dtype(xe.dtype).itemsize))
 
     if counts is None:
         y = pl.pallas_call(
@@ -161,6 +185,7 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
             ],
             out_specs=pl.BlockSpec((1, bc, d), lambda e, ci, fi: (e, ci, 0)),
             out_shape=out_shape,
+            compiler_params=params,
             interpret=interpret,
         )(xe, w_gate, w_up, w_down)
         return y[:, :C_in].astype(xe.dtype)
@@ -189,6 +214,7 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
             functools.partial(_kernel_grouped, act=act, bc=bc),
             grid_spec=grid_spec,
             out_shape=out_shape,
+            compiler_params=params,
             interpret=interpret,
         )(counts.astype(jnp.int32), expert_ids.astype(jnp.int32),
           xe, w_gate, w_up, w_down)
@@ -211,6 +237,7 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
         functools.partial(_kernel_ragged, act=act, bc=bc),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=params,
         interpret=interpret,
     )(counts.astype(jnp.int32), xe, w_gate, w_up, w_down)
     return y[:, :C_in].astype(xe.dtype)

@@ -17,8 +17,38 @@ Conventions (global, whole-step quantities):
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
+from repro.configs import canonical
 from repro.models.config import ModelConfig, layer_pattern
+
+
+# The dry-run's input shapes.  This module imports no JAX, so the
+# ``dryrun --all`` parent can enumerate combinations without holding it.
+@dataclass(frozen=True)
+class ShapeSpec:
+    name: str
+    kind: str          # train | prefill | decode
+    seq: int
+    batch: int
+
+
+SHAPES = {
+    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
+    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
+    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
+    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
+}
+
+# archs allowed to run long_500k (sub-quadratic or windowed decode)
+LONG_OK = {"mamba2_780m", "jamba_1_5_large_398b", "gemma2_9b"}
+
+
+def skip_reason(arch: str, shape: str) -> Optional[str]:
+    if shape == "long_500k" and canonical(arch) not in LONG_OK:
+        return ("pure full-attention arch: long_500k skipped per "
+                "sub-quadratic rule (DESIGN.md §4)")
+    return None
 
 
 @dataclass

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-
 # Multi-pod dry-run: lower + compile every (architecture x input-shape x
 # mesh) combination with ShapeDtypeStruct inputs (no allocation), print
 # memory/cost analysis, and derive the three roofline terms:
@@ -17,14 +13,20 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 # Collective bytes come from the post-SPMD HLO with scan trip-count
 # expansion (launch/hloparse.py).
 #
-# Usage:
+# The production meshes need 256 (512 multi-pod) devices; on a CPU host
+# they come from the caller's environment:
+#   export JAX_PLATFORMS=cpu \
+#          XLA_FLAGS=--xla_force_host_platform_device_count=512
 #   python -m repro.launch.dryrun --arch mixtral-8x7b --shape decode_32k
 #   python -m repro.launch.dryrun --arch ... --shape ... --multi-pod
 #   python -m repro.launch.dryrun --all [--force]    # subprocess per combo
+# The --all parent imports no JAX (the shape table lives in the JAX-free
+# launch/costs.py), so each child is the only process holding it.
 # Results accumulate in reports/dryrun/<arch>__<shape>__<mesh>.json.
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -59,10 +61,10 @@ def model_flops(cfg, spec) -> float:
 def run_one(arch: str, shape: str, multi_pod: bool) -> dict:
     import jax
     from repro.launch import sharding as shd
-    from repro.launch.costs import step_cost
+    from repro.launch.costs import SHAPES, skip_reason, step_cost
     from repro.launch.hloparse import collective_traffic
     from repro.launch.mesh import make_production_mesh
-    from repro.launch.shapes import SHAPES, build, skip_reason
+    from repro.launch.shapes import build
 
     mesh_name = "pod2x16x16" if multi_pod else "pod16x16"
     rec = {"arch": arch, "shape": shape, "mesh": mesh_name,
@@ -151,7 +153,7 @@ def main():
 
     if args.all:
         from repro.configs import ARCHS
-        from repro.launch.shapes import SHAPES
+        from repro.launch.costs import SHAPES
         combos = [(a, s, mp) for a in ARCHS for s in SHAPES
                   for mp in (False, True)]
         failures = []

@@ -1,5 +1,7 @@
 """Faulted expert-parallel serve: degraded-link detection + expert
-re-route on an 8-device host mesh (DESIGN.md §13).
+re-route on a (1, n) mesh over every device JAX sees (DESIGN.md §13) —
+on a CPU host, force the devices from outside with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Serves a cycle of zipf-routed requests through the jitted EP MoE step
 (models/moe_ep.py) three times over the SAME inputs:
@@ -32,17 +34,10 @@ wall-clock ms/step honestly reflects the fault and the saving.
 """
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
 import argparse
 import dataclasses
 import json
+import os
 import time
 from typing import Dict, Optional
 
@@ -52,6 +47,7 @@ import numpy as np
 
 from repro.core.cost_model import LOCAL_PC, LinkTopology, parse_topology
 from repro.launch import sharding as shd
+from repro.launch.mesh import ep_mesh
 from repro.models.config import ModelConfig, MoEConfig
 from repro.models.moe import init_moe
 from repro.models.moe_ep import (apply_moe_ep, ep_applicable,
@@ -104,11 +100,8 @@ def run_resilience_trials(*, steps: int = 26, faults: str = DEFAULT_FAULTS,
     """Healthy / fault-static / fault-reroute trials over one request
     cycle; returns the JSON-ready record with per-trial timings, the
     per-pair byte accounting and the verdicts."""
-    if len(jax.devices()) < 8:
-        raise SystemExit("ep_serve needs 8 devices (host-platform forced; "
-                         "run as a fresh process)")
     cfg, params = build_model(seed=seed)
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = ep_mesh(E)
     tp = mesh.shape["model"]
     topo = (topology if isinstance(topology, LinkTopology)
             else parse_topology(topology, tp, BENCH_PROFILE))
@@ -242,6 +235,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", default=None, help="write the record here")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
 
     res = run_resilience_trials(
         steps=args.steps, faults=args.faults, topology=args.topology,

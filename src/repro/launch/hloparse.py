@@ -208,11 +208,27 @@ def collective_traffic(hlo: str) -> Dict[str, float]:
 
 _DOT_RE = re.compile(r"=\s*([^=]*?)\s+dot\(([^)]*)\)(.*)$")
 _RHS_CONTRACT_RE = re.compile(r"rhs_contracting_dims=\{([0-9,]*)\}")
+# ``%name = dtype[dims]{layout} op(`` — an instruction's result shape,
+# used to resolve operands that newer XLA prints by name only
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"
+                     r"([a-z][0-9a-z]*\[[0-9,]*\])", re.M)
+
+
+def _operand_dims(operand: str, defs: Dict[str, str]):
+    """Dims of one dot operand: its inline shape when the text carries
+    one (``f32[16,4]{1,0} %b``), else the shape of the instruction that
+    defines it (``%b``; instruction names are unique in a module)."""
+    got = shape_dims(operand)
+    if got is None:
+        name = (operand.split() or [""])[-1].lstrip("%")
+        got = shape_dims(defs.get(name, ""))
+    return got[1] if got is not None else None
 
 
 def _line_dot_flops(text: str):
     """Yield ("dot_flops", flops) per dot: 2 x result elements x
     contracted extent (from the rhs operand's contracting dims)."""
+    defs = {m.group(1): m.group(2) for m in _DEF_RE.finditer(text)}
     for line in text.splitlines():
         if " dot(" not in line:
             continue
@@ -225,13 +241,13 @@ def _line_dot_flops(text: str):
         n_out = 1
         for d in res[1]:
             n_out *= d
-        # operands: first shape = lhs, second = rhs
-        shapes = list(_SHAPE_RE.finditer(m.group(2)))
+        # operands: lhs, rhs — with or without inline shapes
+        ops = m.group(2).split(",") if "[" not in m.group(2) else \
+            [s.group(0) for s in _SHAPE_RE.finditer(m.group(2))]
         k = 1
         cm = _RHS_CONTRACT_RE.search(m.group(3))
-        if cm and len(shapes) >= 2 and cm.group(1):
-            rdims = ([int(d) for d in shapes[1].group(2).split(",")]
-                     if shapes[1].group(2) else [])
+        rdims = _operand_dims(ops[1], defs) if len(ops) >= 2 else None
+        if cm and rdims is not None and cm.group(1):
             for ci in cm.group(1).split(","):
                 ci = int(ci)
                 if 0 <= ci < len(rdims):

@@ -106,6 +106,8 @@ def main():
                          "exit non-zero unless outputs are identical")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
 
     cfg = make_smoke(get_config(args.arch)).replace(n_layers=4)
     corpus = MarkovCorpus(vocab=cfg.vocab, seed=args.seed)

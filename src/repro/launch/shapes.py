@@ -16,15 +16,15 @@ ShapeDtypeStructs — no device allocation ever happens for full configs.
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import canonical, get_config
+from repro.configs import get_config
 from repro.launch import sharding as shd
+from repro.launch.costs import SHAPES, ShapeSpec
 from repro.models.config import ModelConfig
 from repro.models.model import init_caches, init_model
 from repro.models.moe import expert_capacity
@@ -32,32 +32,6 @@ from repro.serving.steps import (default_dali_config, init_serve_state,
                                  make_decode_step, make_prefill_step)
 from repro.training.optimizer import OptConfig, init_adamw
 from repro.training.train_step import make_train_step
-
-
-@dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    kind: str          # train | prefill | decode
-    seq: int
-    batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeSpec("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
-}
-
-# archs allowed to run long_500k (sub-quadratic or windowed decode)
-LONG_OK = {"mamba2_780m", "jamba_1_5_large_398b", "gemma2_9b"}
-
-
-def skip_reason(arch: str, shape: str) -> Optional[str]:
-    if shape == "long_500k" and canonical(arch) not in LONG_OK:
-        return ("pure full-attention arch: long_500k skipped per "
-                "sub-quadratic rule (DESIGN.md §4)")
-    return None
 
 
 # --------------------------------------------------------------------------

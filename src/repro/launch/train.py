@@ -72,6 +72,8 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

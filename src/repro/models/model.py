@@ -105,7 +105,8 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
     prompt sits at ``length - 1``, not at ``S - 1``.
 
     ``expert_slots`` (an ``ExpertStore.build_view`` pytree: per-MoE-layer
-    device slot-pool slices, scan entries stacked (n_super, ...)) plus
+    slot tables, scan entries stacked (n_super, ...), and the whole
+    device slot pool under ``"pool_rows"``, a scan constant) plus
     ``slot_fetch`` (the store, for miss fallbacks) switch MoE layers to
     the physical-offload slot path; slot slices thread through the scan
     exactly like caches — with a pipelined store the view additionally
@@ -138,12 +139,20 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
                     else tuple(None for _ in prefix_pat))
     slots_scan = (expert_slots["scan"] if expert_slots is not None
                   else tuple(None for _ in period_pat))
-    # a pipelined store's staged insert rows: one (L, max_moves, ...)
-    # buffer set shared by every layer — closed over by the scan body as
-    # a CONSTANT (indexed [lid, row] inside slot_expert_ffn), never
-    # sliced through the scan's xs like the pools are
+    # a pipelined store's staged insert rows: one (buf_cap, ...) buffer
+    # set shared by every layer — closed over by the scan body as a
+    # CONSTANT (indexed [row] inside slot_expert_ffn), never sliced
+    # through the scan's xs
     slot_inject = (expert_slots.get("inject_rows")
                    if expert_slots is not None else None)
+    # likewise the whole (L, n_slots, ...) slot pool: each layer's slot
+    # dict gets it as a constant, indexed [lid, slot] by the FFN
+    pool = (expert_slots.get("pool_rows")
+            if expert_slots is not None else None)
+
+    def with_pool(s):
+        return s if s is None or pool is None else dict(s, pool=pool)
+
     infos = []
     new_prefix_caches = []
     for i, kinds in enumerate(prefix_pat):
@@ -153,7 +162,7 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
                                  cross_src=cross_src,
                                  moe_capacity=moe_capacity,
                                  count_overlap=count_overlap,
-                                 slots=slots_prefix[i],
+                                 slots=with_pool(slots_prefix[i]),
                                  slot_fetch=slot_fetch,
                                  slot_live=slot_live,
                                  slot_inject=slot_inject,
@@ -173,7 +182,7 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
                                      cross_src=cross_src,
                                      moe_capacity=moe_capacity,
                                      count_overlap=count_overlap,
-                                     slots=s_slices[p],
+                                     slots=with_pool(s_slices[p]),
                                      slot_fetch=slot_fetch,
                                      slot_live=slot_live,
                                      slot_inject=slot_inject,

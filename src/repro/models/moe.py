@@ -231,9 +231,10 @@ def slot_expert_ffn(slots, slot_fetch, xf, idx, gates, cfg: ModelConfig,
     """Physical-offload decode path: weights come from the device slot
     pool instead of a full (E, ...) stack (serving/expert_store.py).
 
-    ``slots`` is one layer's slot view: gate/up (n_slots, d, f), down
-    (n_slots, f, d), slot_of (E,) int32 expert->slot (-1 = not pooled),
-    lid () int32 layer id.  Pooled experts gather their slot rows;
+    ``slots`` is one layer's slot view: slot_of (E,) int32 expert->slot
+    (-1 = not pooled), lid () int32 layer id, and ``pool`` — the whole
+    pool, gate/up (L, n_slots, d, f), down (L, n_slots, f, d), indexed
+    ``[lid, slot]``.  Pooled experts gather their slot rows;
     misses fall back to the host tier via ``slot_fetch`` (an ExpertStore)
     under ``lax.cond`` so fully-resident steps never leave the device:
 
@@ -263,9 +264,10 @@ def slot_expert_ffn(slots, slot_fetch, xf, idx, gates, cfg: ModelConfig,
     if live is not None:
         hit = hit | ~jnp.repeat(live, K)
     srow = jnp.clip(slot, 0)
-    wg = slots["gate"][srow]                       # (T*K, d, f)
-    wu = slots["up"][srow]
-    wd = slots["down"][srow]
+    pool, lid = slots["pool"], slots["lid"]
+    wg = pool["gate"][lid, srow]                   # (T*K, d, f)
+    wu = pool["up"][lid, srow]
+    wd = pool["down"][lid, srow]
     if slot_inject is not None:
         # pipelined offload (DESIGN.md §9): an inserted expert reads its
         # freshly staged inject row (slot_of, built from the post-plan
@@ -341,15 +343,17 @@ def slot_expert_stacks(slots, slot_fetch, counts, cfg: ModelConfig,
     """Assemble FULL (E, ...) gate/up/down stacks for a prefill-sized
     dense sweep from the physical-offload tiers (DESIGN.md §11).
 
-    Pooled experts gather their device slot rows (a pipelined store's
+    Pooled experts copy their device slot rows in (a pipelined store's
     inject rows override the stale pool rows, §9); activated-but-missing
     experts stream from the host store in rank-compacted waves of at
     most ``slot_fetch.prefill_rows`` experts — each wave is one
     ``lax.cond``-guarded ``pure_callback`` (an all-hit layer never pays
-    a host round trip), and because wave w+1's host gather depends only
-    on the routing counts — not on wave w's scatter or the FFN — the
-    runtime overlaps consecutive waves' host work with the device-side
-    scatters (intra-sweep double buffering).  Non-activated experts keep
+    a host round trip) that scatters its rows into the stacks inside the
+    firing branch, so waves run one after another.  Every source lands
+    by in-place row copies into ONE (E, ...) buffer per matrix: at
+    Mixtral widths a layer's stacks are 2.8 GB, and a gather/select
+    formulation kept two or three such buffers live.  Non-activated
+    experts keep
     zero rows: their capacity buckets are empty and the dense combine
     never gathers their output rows (``se == e`` implies
     ``counts[e] > 0``), so zeros are bit-safe and the assembled sweep is
@@ -362,24 +366,45 @@ def slot_expert_stacks(slots, slot_fetch, counts, cfg: ModelConfig,
     Returns ``(stack_params, need)`` — ``need`` is all-False except for
     the host tier."""
     E = slots["slot_of"].shape[0]
-    dt = slots["gate"].dtype
-    d, f = slots["gate"].shape[1], slots["gate"].shape[2]
+    pool, lid = slots["pool"], slots["lid"]
+    dt = pool["gate"].dtype
+    d, f = pool["gate"].shape[2], pool["gate"].shape[3]
     slot_of = slots["slot_of"]
     pooled = slot_of >= 0
-    srow = jnp.clip(slot_of, 0)
-    pw = pooled[:, None, None]
-    wg = jnp.where(pw, slots["gate"][srow], 0)
-    wu = jnp.where(pw, slots["up"][srow], 0)
-    wd = jnp.where(pw, slots["down"][srow], 0)
+    names = ("gate", "up", "down")
+
+    def put_rows(stacks, n, row_of, take):
+        """Copy source row i — ``take(k, i)``, one (d, f) block of matrix
+        k — into the stacks at the expert whose ``row_of`` (E,) entry is
+        i (-1 = none).  Row-at-a-time in-place copies keep ONE (E, ...)
+        buffer per matrix live and never materialize a source slice: at
+        Mixtral widths each stack is 0.94 GB."""
+        e_of = jnp.full((n,), E, jnp.int32).at[
+            jnp.where(row_of >= 0, row_of, n)].set(
+            jnp.arange(E, dtype=jnp.int32), mode="drop")
+
+        def body(i, stacks):
+            e = e_of[i]
+            at = (jnp.minimum(e, E - 1), 0, 0)
+            out = []
+            for st, k in zip(stacks, names):
+                keep = jax.lax.dynamic_slice(st, at, (1,) + st.shape[1:])
+                out.append(jax.lax.dynamic_update_slice(
+                    st, jnp.where(e < E, take(k, i)[None], keep), at))
+            return tuple(out)
+
+        return jax.lax.fori_loop(0, n, body, tuple(stacks))
+
+    wg, wu, wd = put_rows(
+        (jnp.zeros((E, d, f), dt), jnp.zeros((E, d, f), dt),
+         jnp.zeros((E, f, d), dt)),
+        pool["gate"].shape[1], slot_of, lambda k, i: pool[k][lid, i])
     if slot_inject is not None and "inj_of" in slots:
         ipos = slots["inj_of"]                     # (E,) inject row or -1
-        use = ipos >= 0
-        irow = jnp.clip(ipos, 0)
-        uw = use[:, None, None]
-        wg = jnp.where(uw, slot_inject["gate"][irow], wg)
-        wu = jnp.where(uw, slot_inject["up"][irow], wu)
-        wd = jnp.where(uw, slot_inject["down"][irow], wd)
-        pooled = pooled | use
+        # inject rows override the stale pool rows underneath (§9)
+        wg, wu, wd = put_rows((wg, wu, wd), slot_inject["gate"].shape[0],
+                              ipos, lambda k, i: slot_inject[k][i])
+        pooled = pooled | (ipos >= 0)
     need = (counts > 0) & ~pooled
     none = jnp.zeros((E,), bool)
     if slot_fetch.fallback == "little":
@@ -391,7 +416,6 @@ def slot_expert_stacks(slots, slot_fetch, counts, cfg: ModelConfig,
             lambda h: io_callback(slot_fetch.little_miss_cb,
                                   jax.ShapeDtypeStruct((), jnp.int32), h),
             lambda h: jnp.int32(0), ~need)
-        lid = slots["lid"]
 
         def deq(qk, sk):
             q = slot_little[qk][lid].astype(jnp.float32)   # (E, ..., out)
@@ -411,23 +435,22 @@ def slot_expert_stacks(slots, slot_fetch, counts, cfg: ModelConfig,
     shapes = (jax.ShapeDtypeStruct((P, d, f), dt),
               jax.ShapeDtypeStruct((P, d, f), dt),
               jax.ShapeDtypeStruct((P, f, d), dt))
+    def wave(args):
+        rows, *stacks = args
+        staged = dict(zip(names, jax.pure_callback(
+            slot_fetch.prefill_fetch_cb, shapes, lid, rows)))
+        return put_rows(stacks, P, rows, lambda k, i: staged[k][i])
+
     for w in range(n_waves):
         in_wave = need & (rank >= w * P) & (rank < (w + 1) * P)
+        # expert -> staging row of this wave (-1 = not in it); the
+        # staging buffer lives only inside the firing branch, and each
+        # wave consumes the previous one's stacks, so one wave's
+        # (P, ...) staging is live at a time
         rows = jnp.where(in_wave, rank - w * P, -1).astype(jnp.int32)
-        fg, fu, fd = jax.lax.cond(
-            jnp.any(in_wave),
-            lambda r: jax.pure_callback(slot_fetch.prefill_fetch_cb,
-                                        shapes, slots["lid"], r),
-            lambda r: tuple(jnp.zeros(s.shape, s.dtype) for s in shapes),
-            rows)
-        # invert rows -> expert-per-staging-row; experts outside the wave
-        # scatter to the dropped index P, staging pad rows land on E
-        dst = jnp.where(in_wave, rows, P)
-        e_of = jnp.full((P,), E, jnp.int32).at[dst].set(
-            jnp.arange(E, dtype=jnp.int32), mode="drop")
-        wg = wg.at[e_of].set(fg, mode="drop")
-        wu = wu.at[e_of].set(fu, mode="drop")
-        wd = wd.at[e_of].set(fd, mode="drop")
+        wg, wu, wd = jax.lax.cond(jnp.any(in_wave), wave,
+                                  lambda a: tuple(a[1:]),
+                                  (rows, wg, wu, wd))
     return {"gate": wg, "up": wu, "down": wd}, none
 
 

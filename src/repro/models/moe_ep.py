@@ -233,7 +233,6 @@ def apply_moe_ep(params, x, cfg: ModelConfig, *,
     capped demand matrix (one tiny int32 all_gather of the counts the
     exchange already computes) — the global demand view the topology
     placement solver and the per-link byte accounting consume."""
-    from jax.experimental.shard_map import shard_map
     from repro.launch import sharding as shd
     from repro.models.layers import apply_mlp
     from repro.models.moe import expert_capacity, local_dispatch, route
@@ -444,12 +443,12 @@ def apply_moe_ep(params, x, cfg: ModelConfig, *,
     }
     if demand_view:
         info_specs["ep_counts"] = P(None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), w_spec, w_spec, w_spec_dn,
                   shared_specs, tok3, P(None)),
         out_specs=(tok3, info_specs),
-        check_rep=False)
+        check_vma=False)
     y, info = fn(params["router"], params["gate"], params["up"],
                  params["down"], params.get("shared"), x, perm_arr)
     T_all = B * S

@@ -56,8 +56,8 @@ weights sat in device memory and the OffloadPolicy's ``resident`` /
     *inject buffers* ``(buf_cap, ...)`` BEFORE the dispatch: a small
     pool of GLOBAL weight rows shared by all layers, closed over by the
     decode step's ``lax.scan`` body as scan constants (indexed
-    ``[row]``, no per-layer slice copies) while the tiny per-layer
-    expert→row map ``inj_of`` rides the xs like the pool slices.  Each
+    ``[row]``, no per-layer slice copies, like the pool itself) while the
+    tiny per-layer expert→row map ``inj_of`` rides the xs.  Each
     MoE layer resolves its own inserts in-graph right where it gathers
     (``models/moe.py::slot_expert_ffn``), so a decision made after
     step t's sync is readable at step t+1 and the per-step device work
@@ -337,16 +337,26 @@ class ExpertStore:
         self._n_super = n_super
         self.n_layers = len(prefix_moe) + n_super * len(scan_moe)
 
-        # host store: (L, E, ...) per matrix, canonical layer order
+        # host store: (L, E, ...) per matrix, canonical layer order.
+        # Filled one source array at a time, and taken as-is when one scan
+        # position already holds every layer, so host memory peaks at the
+        # store plus one source (none when the caller passes host arrays,
+        # see ``host_expert_params``)
         def stack(name):
-            rows = [np.asarray(params["prefix"][i]["mlp"][name])
-                    for i in prefix_moe]
-            per_pos = [np.asarray(params["scan"][p]["mlp"][name])
+            pre = [params["prefix"][i]["mlp"][name] for i in prefix_moe]
+            per_pos = [params["scan"][p]["mlp"][name]
                        for p in scan_moe]                 # (n_super, E, ..)
-            if per_pos:
-                s = np.stack(per_pos, axis=1)             # (n_super, P, ..)
-                rows.extend(s.reshape((-1,) + s.shape[2:]))
-            return np.stack(rows)
+            if not pre and len(per_pos) == 1:
+                return np.asarray(per_pos[0])
+            first = pre[0] if pre else per_pos[0][0]
+            out = np.empty((self.n_layers,) + tuple(first.shape),
+                           first.dtype)
+            for l, a in enumerate(pre):
+                out[l] = np.asarray(a)
+            P, n_pre = len(per_pos), len(pre)
+            for j, a in enumerate(per_pos):
+                out[n_pre + j::P] = np.asarray(a)
+            return out
 
         self.host = {k: stack(k) for k in ("gate", "up", "down")}
         self.dtype = self.host["gate"].dtype
@@ -655,27 +665,40 @@ class ExpertStore:
                 f"({L}, {self.E}), got {resident.shape} — pass the "
                 f"policy's initial (L, E) bool cache mask")
         cur = np.full((L, S), -1, np.int32)
-        pools = {k: np.zeros((L, S) + self.host[k].shape[2:], self.dtype)
-                 for k in self.host}
+        ids_of = []
         for l in range(L):
-            ids = np.where(resident[l])[0]
+            ids = np.where(resident[l])[0].astype(np.int32)
             if len(ids) > S:
                 raise ValueError(
                     f"layer {l}: {len(ids)} initial residents exceed "
                     f"n_slots={S} (size the pool to cache+prefetch)")
             cur[l, :len(ids)] = ids
-            for k in pools:
-                pools[k][l, :len(ids)] = self.host[k][l, ids]
+            ids_of.append(ids)
         self._cur = cur.copy()
-        off = {k: jax.device_put(v) for k, v in pools.items()}
-        off["cur"] = jax.device_put(cur)
+        names = ("gate", "up", "down")
+
+        def seeded():
+            # built on the device: zero pools, then each layer's residents
+            # scattered in by the donated commit scatter, so the host
+            # stages one layer's rows at a time, never an (L, S, ...)
+            # copy of the pool (7 GB at Mixtral widths)
+            pool = [jnp.zeros((L, S) + self.host[k].shape[2:], self.dtype)
+                    for k in names]
+            c = jnp.full((L, S), -1, jnp.int32)
+            for l, ids in enumerate(ids_of):
+                n = len(ids)
+                if n:
+                    *pool, c = self._apply_jit(
+                        *pool, c, *(self.host[k][l, ids] for k in names),
+                        np.full(n, l, np.int32), np.arange(n, dtype=np.int32),
+                        ids, np.ones(n, bool))
+            return dict(zip(names, pool), cur=c)
+
+        off = seeded()
         # second generation for the streaming ping-pong (same contents).
         # pipelined is single-generation — its inject buffers replace
         # the spare — so it skips the extra O(pool) allocation
-        self._spare = None
-        if self.mode != "pipelined":
-            self._spare = {k: jax.device_put(v) for k, v in pools.items()}
-            self._spare["cur"] = jax.device_put(cur)
+        self._spare = seeded() if self.mode != "pipelined" else None
         self._spare_lag = np.zeros((0, 3), np.int32)
         self._staged = None
         self._staged_rows = None
@@ -688,13 +711,36 @@ class ExpertStore:
             self._idle_inj = off["inject"]
         return off
 
+    def state_shapes(self):
+        """``jax.ShapeDtypeStruct`` tree of ``init_device_state``'s result,
+        for lowering a step against this store without seeding a pool."""
+        L, S, E, d, f = self.n_layers, self.n_slots, self.E, self.d, self.f
+        sds = jax.ShapeDtypeStruct
+        i32 = jnp.int32
+        off = {"gate": sds((L, S, d, f), self.dtype),
+               "up": sds((L, S, d, f), self.dtype),
+               "down": sds((L, S, f, d), self.dtype),
+               "cur": sds((L, S), i32)}
+        if self.mode == "pipelined":
+            B = self._buf_cap
+            off["inject"] = {"gate": sds((B, d, f), self.dtype),
+                             "up": sds((B, d, f), self.dtype),
+                             "down": sds((B, f, d), self.dtype),
+                             "inj_of": sds((L, E), i32),
+                             "cur": sds((L, S), i32)}
+        return off
+
     # -- the slot-indexed view the model consumes --------------------------
 
     def build_view(self, off):
         """params-shaped per-layer slot view for ``apply_model``:
-        ``{"prefix": (...), "scan": (...)}`` with per-MoE-layer entries
-        ``{"gate","up","down","slot_of","lid"}`` (scan entries carry a
-        leading n_super axis and ride the scan's xs exactly like caches).
+        ``{"prefix": (...), "scan": (...), "pool_rows": {...}}`` with
+        per-MoE-layer entries ``{"slot_of","lid"}`` (scan entries carry a
+        leading n_super axis and ride the scan's xs exactly like caches)
+        and the WHOLE ``(L, n_slots, ...)`` pool once under
+        ``"pool_rows"``, a scan constant the FFN indexes ``[lid, slot]``
+        — slicing it through the xs would materialize each layer's
+        ``(n_slots, d, f)`` slice (1.8 GB at Mixtral widths).
         Traced-friendly — called inside the jitted decode step.
 
         With a pipelined ``off["inject"]`` present (DESIGN.md §9) the
@@ -722,8 +768,7 @@ class ExpertStore:
 
         prefix = [None] * len(prefix_pat)
         for l, i in enumerate(self._prefix_moe):
-            prefix[i] = {"gate": off["gate"][l], "up": off["up"][l],
-                         "down": off["down"][l], "slot_of": slot_of[l],
+            prefix[i] = {"slot_of": slot_of[l],
                          "lid": jnp.asarray(l, jnp.int32)}
             if inj is not None:
                 prefix[i]["inj_of"] = inj["inj_of"][l]
@@ -736,14 +781,12 @@ class ExpertStore:
                 return r[:, j]
             for j, p in enumerate(self._scan_moe):
                 lids = n_pre + np.arange(self._n_super) * P + j
-                scan[p] = {"gate": per_pos(off["gate"], j),
-                           "up": per_pos(off["up"], j),
-                           "down": per_pos(off["down"], j),
-                           "slot_of": per_pos(slot_of, j),
+                scan[p] = {"slot_of": per_pos(slot_of, j),
                            "lid": jnp.asarray(lids, jnp.int32)}
                 if inj is not None:
                     scan[p]["inj_of"] = per_pos(inj["inj_of"], j)
-        view = {"prefix": tuple(prefix), "scan": tuple(scan)}
+        view = {"prefix": tuple(prefix), "scan": tuple(scan),
+                "pool_rows": {k: off[k] for k in ("gate", "up", "down")}}
         if inj is not None:
             view["inject_rows"] = {"gate": inj["gate"], "up": inj["up"],
                                    "down": inj["down"]}
@@ -1364,6 +1407,36 @@ for _name, _fn, _kind in (
         ("prefill_host", ExpertStore.prefill_host_cb, "pure")):
     register_callback_seam(_name, _fn, kind=_kind)
 del _name, _fn, _kind
+
+
+def host_expert_params(params, cfg: ModelConfig):
+    """Params whose routed expert gate/up/down stacks are host numpy
+    arrays, with their device buffers FREED — the caller's own device
+    copies are deleted, so only use the returned tree afterwards.
+
+    Pass the result to ``ServeSpec.resolve`` for a physical offload mode
+    at full width: the store then takes the host arrays without copying,
+    and the device never holds the full-resident stacks beside the slot
+    pool (at Mixtral-8x7B widths the two would not fit one chip)."""
+    prefix_moe, scan_moe, _ = moe_layer_layout(cfg)
+
+    def to_host(mlp):
+        out = dict(mlp)
+        for k in ("gate", "up", "down"):
+            a = mlp[k]
+            if isinstance(a, jax.Array):
+                out[k] = np.asarray(a)
+                a.delete()
+        return out
+
+    out = dict(params)
+    out["prefix"] = tuple(
+        dict(b, mlp=to_host(b["mlp"])) if i in prefix_moe else b
+        for i, b in enumerate(params["prefix"]))
+    out["scan"] = tuple(
+        dict(b, mlp=to_host(b["mlp"])) if p in scan_moe else b
+        for p, b in enumerate(params["scan"]))
+    return out
 
 
 def strip_expert_params(params, cfg: ModelConfig):

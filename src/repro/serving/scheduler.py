@@ -111,6 +111,10 @@ class Request:
     submitted_at: float = 0.0
     not_before: float = 0.0             # virtual arrival time (0 = now)
     output: List[int] = field(default_factory=list)
+    # set to [] to record, for every token in ``output``, the (V,) logits
+    # row it was sampled from (host copies — for correctness checks,
+    # e.g. chip_smoke.py); None records nothing and costs nothing
+    logits: Optional[list] = None
     first_token_at: float = 0.0
     done_at: float = 0.0
 
@@ -223,6 +227,18 @@ def _pop_arrived(queue: deque, now: float) -> Optional[Request]:
     return None
 
 
+def _record_logits(reqs, rows, logits):
+    """Append row ``rows[j]`` of a step's (B, 1, V) ``logits`` to
+    ``reqs[j].logits`` for the requests that asked for them (one host
+    copy per step, only when some request records)."""
+    if not any(r.logits is not None for r in reqs):
+        return
+    host = np.asarray(logits[:, -1])
+    for r, i in zip(reqs, rows):
+        if r.logits is not None:
+            r.logits.append(host[i])
+
+
 def _bucket_len(n: int, min_bucket: int, cap: int) -> int:
     """Power-of-two padding bucket for prompt lengths: bounds the number of
     distinct prefill compilations to O(log max_len) instead of one per
@@ -323,14 +339,13 @@ class ContinuousBatchServer:
             # last decode; commit it so the admission sweep reads a
             # coherent pool (prefill_barrier, DESIGN.md §11)
             state["offload"] = self.store.prefill_barrier(state["offload"])
-            first_tok, fresh = self._prefill(self.params, jnp.asarray(toks),
-                                             self._fresh_caches,
-                                             jnp.asarray(L, jnp.int32),
-                                             state["offload"])
+            first_tok, fresh, logits = self._prefill(
+                self.params, jnp.asarray(toks), self._fresh_caches,
+                jnp.asarray(L, jnp.int32), state["offload"])
         else:
-            first_tok, fresh = self._prefill(self.params, jnp.asarray(toks),
-                                             self._fresh_caches,
-                                             jnp.asarray(L, jnp.int32))
+            first_tok, fresh, logits = self._prefill(
+                self.params, jnp.asarray(toks), self._fresh_caches,
+                jnp.asarray(L, jnp.int32))
         state = self._admit(state, fresh, first_tok,
                             jnp.asarray(slot, jnp.int32),
                             jnp.asarray(L, jnp.int32))
@@ -339,6 +354,7 @@ class ContinuousBatchServer:
         self.metrics.prefill_s += t1 - t0
         self.metrics.prefill_tokens += L
         req.output.append(int(np.asarray(first_tok)[0, 0]))
+        _record_logits([req], [0], logits[:, None])
         req.first_token_at = t1
         return state
 
@@ -390,11 +406,13 @@ class ContinuousBatchServer:
                 state["offload"] = self.store.pre_step(
                     state["offload"], self.offload, pool_target)
                 self._decode.react()     # follow the degradation ladder
-            state, _, tel = self._decode(self.params, state, self.res_vecs)
+            state, logits, tel = self._decode(self.params, state,
+                                              self.res_vecs)
             if self.store is not None:
                 self.store.post_dispatch(self.offload, pool_target)
             toks = np.asarray(state["tokens"])[:, 0]
             t1 = time.perf_counter()
+            _record_logits([slot_req[i] for i in busy], busy, logits)
             if self.store is not None:
                 pool_target = self.store.next_target(state, tel)
 
@@ -515,12 +533,12 @@ class BatchServer:
         t0 = time.perf_counter()
         if self.store is not None:
             state["offload"] = self.store.prefill_barrier(state["offload"])
-            tok, caches = self._prefill(self.params, jnp.asarray(prompts),
-                                        state["caches"], None,
-                                        state["offload"])
+            tok, caches, logits = self._prefill(
+                self.params, jnp.asarray(prompts), state["caches"], None,
+                state["offload"])
         else:
-            tok, caches = self._prefill(self.params, jnp.asarray(prompts),
-                                        state["caches"])
+            tok, caches, logits = self._prefill(
+                self.params, jnp.asarray(prompts), state["caches"])
         tok.block_until_ready()
         t_pf = time.perf_counter()
         self.metrics.prefill_s += t_pf - t0
@@ -532,6 +550,7 @@ class BatchServer:
         # as the continuous server, so the serving benchmark compares like
         # with like: outputs include it, TTFT points at it)
         toks0 = np.asarray(tok)[:, 0]
+        _record_logits(wave, range(len(wave)), logits[:, None])
         live = np.array([i < len(wave) for i in range(B)])
         for i, r in enumerate(wave):
             if live[i]:
@@ -561,6 +580,8 @@ class BatchServer:
             t_step = time.perf_counter()
             if self.store is not None:
                 pool_target = self.store.next_target(state, tel)
+            _record_logits([r for i, r in enumerate(wave) if live[i]],
+                           np.nonzero(live[:len(wave)])[0], logits)
             for i, r in enumerate(wave):
                 if live[i]:
                     r.output.append(int(toks[i]))

@@ -114,7 +114,8 @@ class OffloadSpec:
     faults        — fault-injection schedule (serving/faults.py); the
                     grammar takes an optional link selector,
                     ``link_degrade[0>3]:x8@6-18`` (DESIGN.md §13)
-    cost_model    — link constants for the watchdog (None = LOCAL_PC)
+    cost_model    — link constants for the watchdog (None = the running
+                    device's profile, cost_model.DEVICE_PROFILES)
     topology      — per-link fabric spec (core/cost_model.parse_topology:
                     "flat", "island:K", "SRC>DST:xF" overrides, or a
                     LinkTopology) attached to the cost model so per-link
@@ -220,7 +221,7 @@ def build_store(offload: str, params, cfg, policy, fallback: str = "fetch",
         from repro.core.cost_model import CostModel, parse_topology
         cm = cost_model if cost_model is not None else CostModel.for_config(cfg)
         cost_model = cm.with_topology(
-            parse_topology(topology, len(jax.devices())))
+            parse_topology(topology, len(jax.devices()), cm.profile))
     dcfg = policy.dcfg
     moves = max(2, dcfg.prefetch_size + dcfg.u_size)
     # pool = max effective resident set (cache ∪ prefetch) + one plan of

@@ -102,7 +102,7 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
                       moe_capacity: Optional[int] = None,
                       offload=None, fallback=None):
     """Returns prefill(params, tokens (B,S), caches, cross_src, off) ->
-    (next_token (B,1), caches).
+    (next_token (B,1), caches, logits (B, V) at the sampled position).
 
     ``offload`` (an :class:`~repro.serving.expert_store.ExpertStore`)
     runs the prefill layer sweep through the physical slot path
@@ -128,7 +128,7 @@ def make_prefill_step(cfg: ModelConfig, max_len: int,
                                         moe_capacity=moe_capacity,
                                         last_logit_only=True, **slot_kw)
         next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_tok, caches
+        return next_tok, caches, logits[:, -1]
 
     return prefill
 
@@ -141,7 +141,8 @@ def make_admit_prefill(cfg: ModelConfig,
     the first generated token samples from the logit at ``length - 1``
     (identical to running the unpadded prompt alone — per-slot position
     correctness).  Returns prefill(params, tokens (1,Sb), caches, length,
-    off) -> (next_token (1,1), caches).  Compiles once per bucket length.
+    off) -> (next_token (1,1), caches, logits (1, V) at ``length - 1``).
+    Compiles once per bucket length.
 
     ``offload`` streams the admission sweep through the physical slot
     path exactly like ``make_prefill_step`` — right-pad tokens route and
@@ -163,7 +164,7 @@ def make_admit_prefill(cfg: ModelConfig,
                                         moe_capacity=moe_capacity,
                                         logit_index=length - 1, **slot_kw)
         next_tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_tok, caches
+        return next_tok, caches, logits[:, -1]
 
     return prefill
 
@@ -368,8 +369,19 @@ class ResilientDecode:
             pol = self.offload.degraded_policy(self.policy)
             fb = "little" if rung == "little" else None
         with _internal():      # variant builds are not legacy call sites
-            fn = make_decode_step(self.cfg, policy=pol, offload=self.offload,
-                                  fallback=fb, **self._kw)
+            step = make_decode_step(self.cfg, policy=pol,
+                                    offload=self.offload, fallback=fb,
+                                    **self._kw)
+
+        def fn(params, state, res_vecs=None):
+            # the slot pool goes in and never comes out: it is the
+            # store's, and an undonated output would make XLA copy the
+            # whole pool every step (7 GB at Mixtral widths); __call__
+            # hands the caller's pool back on the new state
+            new_state, logits, tel = step(params, state, res_vecs)
+            new_state.pop("offload", None)
+            return new_state, logits, tel
+
         jit = self._jit if jit is None else jit
         return jax.jit(fn) if jit else fn
 
@@ -399,7 +411,10 @@ class ResilientDecode:
         fn = self._variants.get(rung)
         if fn is None:
             fn = self._variants[rung] = self._build(rung)
-        return fn(params, state, res_vecs)
+        new_state, logits, tel = fn(params, state, res_vecs)
+        if "offload" in state:
+            new_state["offload"] = state["offload"]
+        return new_state, logits, tel
 
 
 def init_serve_state(cfg: ModelConfig, batch: int, max_len: int,
@@ -434,14 +449,15 @@ def default_dali_config(cfg: ModelConfig, cache_ratio: float = 0.25,
                         prefetch_size: int = 1, w_size: int = 4,
                         u_size: int = 1) -> Optional[DaliConfig]:
     """Paper defaults: cache 25-50% of experts/layer; (w,u)=(4,1) Mixtral-
-    like, (4,8) for many-expert models (§6.4)."""
+    like, (4,8) for many-expert models (§6.4).  Cost constants come from
+    the running device's profile (``cost_model.profile_for_device``)."""
     if cfg.moe is None:
         return None
-    from repro.core.cost_model import CostModel, LOCAL_PC
+    from repro.core.cost_model import CostModel
     from repro.models.config import layer_pattern
     n_moe = sum(1 for _, mlp in layer_pattern(cfg) if mlp == "moe")
     E = cfg.moe.n_routed
-    cm = CostModel.for_config(cfg, LOCAL_PC)
+    cm = CostModel.for_config(cfg)          # priced for the device in use
     return DaliConfig.from_cost_model(
         cm, n_moe_layers=n_moe, n_experts=E,
         cache_size=max(1, int(E * cache_ratio)),

@@ -167,7 +167,7 @@ def test_per_slot_decode_matches_shared_decode(small_moe):
     state = init_serve_state(cfg, B, max_len)
     prefill = jax.jit(make_prefill_step(cfg, max_len))
     decode = jax.jit(make_decode_step(cfg))
-    nxt, caches = prefill(params, toks, state["caches"])
+    nxt, caches, _ = prefill(params, toks, state["caches"])
     state = dict(state, tokens=nxt, caches=caches,
                  pos=jnp.asarray(S, jnp.int32))
     ref = [np.asarray(nxt)[:, 0].copy()]
@@ -182,7 +182,7 @@ def test_per_slot_decode_matches_shared_decode(small_moe):
     ps = init_serve_state(cfg, B, max_len, per_slot=True)
     for b in range(B):
         fresh = init_caches(cfg, 1, max_len)
-        tok1, fresh = admit_prefill(params, toks[b:b + 1], fresh,
+        tok1, fresh, _ = admit_prefill(params, toks[b:b + 1], fresh,
                                     jnp.asarray(S, jnp.int32))
         ps = admit(ps, fresh, tok1, jnp.asarray(b, jnp.int32),
                    jnp.asarray(S, jnp.int32))
@@ -219,7 +219,7 @@ def test_decode_telemetry_masks_retired_slots(small_moe):
     state = init_serve_state(cfg, B, max_len, dali_cfg=dcfg, per_slot=True)
     for b in range(B):
         fresh = init_caches(cfg, 1, max_len)
-        tok1, fresh = admit_prefill(params, toks[b:b + 1], fresh,
+        tok1, fresh, _ = admit_prefill(params, toks[b:b + 1], fresh,
                                     jnp.asarray(S, jnp.int32))
         state = admit(state, fresh, tok1, jnp.asarray(b, jnp.int32),
                       jnp.asarray(S, jnp.int32))
@@ -305,3 +305,21 @@ def test_make_server_presets(small_moe):
                                   max_len=32), BatchServer)
     with pytest.raises(ValueError):
         make_server("nope", params, cfg)
+
+
+@pytest.mark.parametrize("server_cls", [ContinuousBatchServer, BatchServer])
+def test_requested_logits_are_the_sampled_rows(small_moe, server_cls):
+    """``Request(logits=[])`` records one (V,) row per emitted token — the
+    row the greedy token was taken from; other requests record nothing."""
+    cfg, params = small_moe
+    server = server_cls(params, cfg, batch_size=2, max_len=64,
+                        eos_id=NO_EOS)
+    for i, p in enumerate(_prompts(cfg, [7, 11, 9])):
+        server.submit(Request(rid=i, prompt=p, max_new_tokens=4,
+                              logits=[] if i != 1 else None))
+    done = sorted(server.run(), key=lambda r: r.rid)
+    assert done[1].logits is None
+    for r in (done[0], done[2]):
+        rows = np.stack(r.logits)
+        assert rows.shape == (len(r.output), cfg.vocab)
+        assert np.argmax(rows, axis=-1).tolist() == r.output

@@ -147,9 +147,10 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.ep_serve import build_model, zipf_request, E
     from repro.launch import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models.moe_ep import apply_moe_ep, permute_expert_params
     cfg, params = build_model()
-    mesh = jax.make_mesh((1, 8), ('data', 'model'))
+    mesh = make_mesh((1, 8), ('data', 'model'))
     dt = jnp.dtype(cfg.dtype)
     x = zipf_request(4, 160, dt, 11)
     lmap = shd.logical_map_for(cfg, 'prefill_32k', mesh)

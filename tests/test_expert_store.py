@@ -431,3 +431,62 @@ def test_pipelined_decision_readable_at_t_plus_1(model):
     assert not (np.asarray(off_o["cur"]) == e_star).any()
     off_o = store_o.pre_step(off_o, "overlap", target)   # boundary commit
     assert (np.asarray(off_o["cur"]) == e_star).any()
+
+
+# --------------------------------------------------------------------------
+# (f) full-width hand-off: expert stacks on the host, device copies freed
+# --------------------------------------------------------------------------
+
+def test_host_expert_params_frees_device_copies(model):
+    from repro.serving.expert_store import host_expert_params
+    cfg, params = model
+    own = jax.tree.map(jnp.copy, params)          # the helper deletes these
+    dev_stacks = [own["scan"][0]["mlp"][k] for k in ("gate", "up", "down")]
+    host = host_expert_params(own, cfg)
+    assert all(a.is_deleted() for a in dev_stacks)
+    mlp = host["scan"][0]["mlp"]
+    assert all(isinstance(mlp[k], np.ndarray) for k in ("gate", "up", "down"))
+    assert isinstance(mlp["router"], jax.Array)   # non-expert weights stay
+    a = ExpertStore(params, cfg, n_slots=4)
+    b = ExpertStore(host, cfg, n_slots=4)
+    for k in ("gate", "up", "down"):
+        np.testing.assert_array_equal(a.host[k], b.host[k])
+    # one scan position holds every layer: the store takes it without a copy
+    assert b.host["gate"] is mlp["gate"]
+
+
+def test_server_from_host_params_matches_resident(model):
+    """A pipelined server built from host-resident expert stacks serves
+    exactly what the full-resident server serves, logits included."""
+    from repro.serving.expert_store import host_expert_params
+    from repro.serving.scheduler import Request
+    from repro.serving.spec import OffloadSpec, ServeSpec
+    cfg, params = model
+    runs = {}
+    for mode, p in (("modeled", params),
+                    ("pipelined", host_expert_params(
+                        jax.tree.map(jnp.copy, params), cfg))):
+        rng = np.random.default_rng(5)
+        srv = ServeSpec(cfg=cfg, policy="dali", batch_size=2, max_len=32,
+                        offload=OffloadSpec(mode=mode)).resolve(p).server()
+        for i in range(3):
+            srv.submit(Request(
+                rid=i, prompt=rng.integers(2, cfg.vocab, 9).astype(np.int32),
+                max_new_tokens=4, logits=[]))
+        runs[mode] = sorted(srv.run(), key=lambda r: r.rid)
+    for a, b in zip(runs["modeled"], runs["pipelined"]):
+        assert a.output == b.output
+        np.testing.assert_array_equal(np.stack(a.logits), np.stack(b.logits))
+
+
+@pytest.mark.parametrize("mode", ["blocking", "pipelined"])
+def test_state_shapes_match_seeded_state(model, mode):
+    """``state_shapes`` (lowering without seeding a pool) describes
+    exactly the tree ``init_device_state`` builds."""
+    cfg, params = model
+    store = ExpertStore(params, cfg, n_slots=4, mode=mode)
+    pol = resolve_policy("dali", cfg)
+    off = store.init_device_state(np.asarray(pol.init()["resident"]))
+    got = jax.tree.map(lambda a: (a.shape, a.dtype), off)
+    want = jax.tree.map(lambda s: (s.shape, s.dtype), store.state_shapes())
+    assert got == want

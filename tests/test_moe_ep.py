@@ -14,7 +14,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models.config import MoEConfig, ModelConfig
     from repro.models.moe import apply_moe, init_moe
     from repro.launch import sharding as shd
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ('data', 'model'))
     for shared, mode, rt in [(1, 'tp', 'softmax_topk'), (0, 'tp', 'topk_softmax'),
                              (2, 'fsdp', 'softmax_topk'), (0, 'fsdp', 'sigmoid')]:
         cfg = ModelConfig(d_model=64, d_ff=128, dtype='float32',

@@ -24,12 +24,13 @@ SCRIPT = textwrap.dedent("""
     from repro.models.moe import apply_moe, init_moe
     from repro.models.moe_ep import ep_applicable, exchange_ladder
     from repro.launch import sharding as shd
+    from repro.launch.mesh import make_mesh
 
     assert exchange_ladder(64) == [4, 8, 16, 32, 64]
     assert exchange_ladder(96) == [4, 8, 16, 32, 64, 96]
     assert exchange_ladder(4) == [4]
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = make_mesh((2, 4), ('data', 'model'))
     B, S, d, E, K = 4, 128, 64, 64, 2
     C = (B // 2) * (S // 4)                       # cf=0: per-device T_my
 

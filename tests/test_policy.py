@@ -28,6 +28,15 @@ def _dcfg(**kw):
     return DaliConfig(**base)
 
 
+def _fixture_prng():
+    """The fixture's initial cache was drawn with JAX's original threefry
+    bit layout; JAX 0.5 made ``jax_threefry_partitionable`` the default,
+    which draws different permutations from the same key.  Seed the
+    initial state under the recorded layout so the decisions are checked
+    against the recording itself."""
+    return jax.threefry_partitionable(False)
+
+
 def _fixture_trace():
     """The exact deterministic trace the pre-refactor fixture was recorded
     on (seed 42; steps >= 4 carry a live-token mask)."""
@@ -56,7 +65,8 @@ def test_dali_policy_bit_identical_to_prerefactor_fixture():
     dcfg = _dcfg()
     routers, res_vecs, steps = _fixture_trace()
     pol = make_policy("dali", dcfg, top_k=2)
-    state = pol.init()
+    with _fixture_prng():
+        state = pol.init()
     step_fn = jax.jit(pol.step)
     for i, (wl, gi, mask) in enumerate(steps):
         state, dec = step_fn(state, wl,
@@ -81,7 +91,8 @@ def test_compat_wrapper_matches_fixture():
     fx = np.load(FIXTURE)
     dcfg = _dcfg()
     routers, res_vecs, steps = _fixture_trace()
-    state = init_dali_state(dcfg)
+    with _fixture_prng():
+        state = init_dali_state(dcfg)
     for i, (wl, gi, mask) in enumerate(steps):
         state, tel = dali_schedule(state, wl, gi, routers, res_vecs, dcfg,
                                    top_k=2, token_mask=mask)

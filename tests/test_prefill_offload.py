@@ -88,13 +88,13 @@ def test_prefill_slot_bit_identical(model, mode):
     toks = jnp.asarray(np.random.default_rng(3).integers(
         1, cfg.vocab, (B, S)), jnp.int32)
     caches0 = init_caches(cfg, B, MAX_LEN)
-    ref_tok, ref_caches = jax.jit(make_prefill_step(cfg, MAX_LEN))(
+    ref_tok, ref_caches, _ = jax.jit(make_prefill_step(cfg, MAX_LEN))(
         params, toks, caches0)
 
     rs = _resolve(cfg, params, mode)
     assert not _has_expert_stacks(rs.params)     # resolve() stripped them
     state = rs.init_state(batch=B)
-    tok, caches = jax.jit(rs.prefill_step())(
+    tok, caches, _ = jax.jit(rs.prefill_step())(
         rs.params, toks, caches0, None, state["offload"])
     np.testing.assert_array_equal(np.asarray(ref_tok), np.asarray(tok))
     _assert_tree_equal(ref_caches, caches)
@@ -117,12 +117,12 @@ def test_admit_prefill_slot_bit_identical(model, mode):
     toks = jnp.asarray(toks)
     length = jnp.asarray(L, jnp.int32)
     caches0 = init_caches(cfg, 1, MAX_LEN)
-    ref_tok, ref_caches = jax.jit(make_admit_prefill(cfg))(
+    ref_tok, ref_caches, _ = jax.jit(make_admit_prefill(cfg))(
         params, toks, caches0, length)
 
     rs = _resolve(cfg, params, mode)
     state = rs.init_state(batch=1)
-    tok, caches = jax.jit(rs.admit_prefill())(
+    tok, caches, _ = jax.jit(rs.admit_prefill())(
         rs.params, toks, caches0, length, state["offload"])
     np.testing.assert_array_equal(np.asarray(ref_tok), np.asarray(tok))
     _assert_tree_equal(ref_caches, caches)
@@ -140,14 +140,14 @@ def test_prefill_forced_miss_streams_all_activated(model, mode):
     toks = jnp.asarray(np.random.default_rng(9).integers(
         1, cfg.vocab, (B, S)), jnp.int32)
     caches0 = init_caches(cfg, B, MAX_LEN)
-    ref_tok, ref_caches = jax.jit(make_prefill_step(cfg, MAX_LEN))(
+    ref_tok, ref_caches, _ = jax.jit(make_prefill_step(cfg, MAX_LEN))(
         params, toks, caches0)
 
     # prefill_rows=4 << E=16: an all-miss layer needs several waves
     rs = _resolve(cfg, params, mode, prefill_rows=4)
     state = rs.init_state(batch=B)
     off = _wreck_pool(rs, state["offload"])
-    tok, caches = jax.jit(rs.prefill_step())(
+    tok, caches, _ = jax.jit(rs.prefill_step())(
         rs.params, toks, caches0, None, off)
     np.testing.assert_array_equal(np.asarray(ref_tok), np.asarray(tok))
     _assert_tree_equal(ref_caches, caches)
@@ -175,12 +175,12 @@ def test_prefill_chunked_slot_parity(model, monkeypatch):
     caches0 = init_caches(cfg, B, MAX_LEN)
     # the reference traces under the same chunking, so the parity below
     # isolates the slot path (not chunked-vs-unchunked float order)
-    ref_tok, ref_caches = jax.jit(make_prefill_step(cfg, MAX_LEN))(
+    ref_tok, ref_caches, _ = jax.jit(make_prefill_step(cfg, MAX_LEN))(
         params, toks, caches0)
 
     rs = _resolve(cfg, params, "pipelined")
     state = rs.init_state(batch=B)
-    tok, caches = jax.jit(rs.prefill_step())(
+    tok, caches, _ = jax.jit(rs.prefill_step())(
         rs.params, toks, caches0, None, state["offload"])
     np.testing.assert_array_equal(np.asarray(ref_tok), np.asarray(tok))
     _assert_tree_equal(ref_caches, caches)
@@ -197,13 +197,13 @@ def test_prefill_host_tier_allclose(model):
     toks = jnp.asarray(np.random.default_rng(17).integers(
         1, cfg.vocab, (B, S)), jnp.int32)
     caches0 = init_caches(cfg, B, MAX_LEN)
-    ref_tok, ref_caches = jax.jit(make_prefill_step(cfg, MAX_LEN))(
+    ref_tok, ref_caches, _ = jax.jit(make_prefill_step(cfg, MAX_LEN))(
         params, toks, caches0)
 
     rs = _resolve(cfg, params, "blocking", fallback="host")
     state = rs.init_state(batch=B)
     off = _wreck_pool(rs, state["offload"])      # all activated miss
-    tok, caches = jax.jit(rs.prefill_step())(
+    tok, caches, _ = jax.jit(rs.prefill_step())(
         rs.params, toks, caches0, None, off)
     # materialize BEFORE reading counters — dispatch is async, so the
     # callbacks only have provably fired once the outputs are ready

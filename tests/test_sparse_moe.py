@@ -114,7 +114,7 @@ def test_decode_step_fast_path_matches_dense_per_slot_masked():
         st = init_serve_state(cfg, B, max_len, dali_cfg=dcfg, per_slot=True)
         for b in range(B):
             fresh = init_caches(cfg, 1, max_len)
-            t1, fresh = admit_prefill(params, toks[b:b + 1], fresh,
+            t1, fresh, _ = admit_prefill(params, toks[b:b + 1], fresh,
                                       jnp.asarray(S, jnp.int32))
             st = admit(st, fresh, t1, jnp.asarray(b, jnp.int32),
                        jnp.asarray(S, jnp.int32))

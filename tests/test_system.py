@@ -73,7 +73,7 @@ def test_decode_step_with_dali_engine(small_moe):
     prefill = jax.jit(make_prefill_step(cfg, 32))
     decode = jax.jit(make_decode_step(cfg, dcfg))
     toks = jax.random.randint(jax.random.PRNGKey(4), (B, S), 0, cfg.vocab)
-    nxt, caches = prefill(params, toks, state["caches"])
+    nxt, caches, _ = prefill(params, toks, state["caches"])
     state = dict(state, tokens=nxt, caches=caches,
                  pos=jnp.asarray(S, jnp.int32))
     hits = 0
