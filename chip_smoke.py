@@ -65,11 +65,13 @@ class CompileClock:
     def __init__(self):
         import jax
         self.total = 0.0
+        self.cumulative = 0.0
         jax.monitoring.register_event_duration_secs_listener(self._on)
 
     def _on(self, event, duration, **_):
         if event == self.EVENT:
             self.total += duration
+            self.cumulative += duration
 
     def lap(self) -> float:
         t, self.total = self.total, 0.0
@@ -107,16 +109,17 @@ def mem(dev) -> dict:
     return out
 
 
-def watch_memory(dev, every_s: float = 20.0):
-    """Print device and host memory every ``every_s`` seconds from a
-    daemon thread, so a run the machine kills for memory still shows
-    where it stood."""
+def watch_memory(dev, clock, every_s: float = 20.0):
+    """Print device and host memory, and the compile seconds so far,
+    every ``every_s`` seconds from a daemon thread, so a run the machine
+    kills for memory still shows where it stood."""
     t0 = time.perf_counter()
 
     def loop():
         while True:
             time.sleep(every_s)
-            say(f"[memory at {time.perf_counter() - t0:.0f}s] {mem(dev)}")
+            say(f"[memory at {time.perf_counter() - t0:.0f}s, compiled "
+                f"{clock.cumulative:.0f}s] {mem(dev)}")
 
     threading.Thread(target=loop, daemon=True).start()
 
@@ -148,14 +151,22 @@ def serve(params, cfg, mode: str, reqs, dali_cfg):
                      dali_cfg=dali_cfg, batch_size=BATCH,
                      max_len=PROMPT + NEW,
                      offload=OffloadSpec(mode=mode))
+    t0 = time.perf_counter()
     rs = spec.resolve(params)
+    t1 = time.perf_counter()
     kernel = prefill_has_kernel(rs, cfg)
-    say(f"  {mode}: resolved, prefill compiled for the kernel check | "
+    say(f"  {mode}: resolved in {t1 - t0:.1f}s, prefill compiled for the "
+        f"kernel check in {time.perf_counter() - t1:.1f}s | "
         f"{mem(jax.devices()[0])}")
     srv = rs.server()
     for i, p in enumerate(reqs):
         srv.submit(Request(rid=i, prompt=p, max_new_tokens=NEW, logits=[]))
+    t0 = time.perf_counter()
     done = srv.run()
+    m = srv.metrics
+    say(f"  {mode}: served in {time.perf_counter() - t0:.1f}s (prefill "
+        f"{m.prefill_s:.1f}s, decode {m.decode_s:.1f}s over {m.steps} "
+        f"steps)")
     return {r.rid: r for r in done}, rs, kernel
 
 
@@ -211,8 +222,18 @@ def compare(ref, got) -> None:
         f"logit| prefill={d_pre!r} decode={d_dec!r} (bit-equal expected)")
     if not tokens_equal:
         for rid in sorted(ref):
-            say(f"  request {rid}: A {ref[rid].output}\n"
-                f"              B {got[rid].output}")
+            a, b = ref[rid], got[rid]
+            la, lb = np.stack(a.logits), np.stack(b.logits)
+            i = next((j for j, (x, y) in enumerate(zip(a.output, b.output))
+                      if x != y), len(a.output))
+            # logits up to and including the first differing token were
+            # computed from the same inputs in both runs
+            same = np.abs(la[:i + 1] - lb[:i + 1]).max(axis=1)
+            top2 = (np.sort(la[i])[-2:] if i < len(la) else [0.0, 0.0])
+            say(f"  request {rid}: first differing token {i} | per-token "
+                f"max |delta logit| up to it {same.tolist()} | A's top-2 "
+                f"gap there {float(top2[1] - top2[0])!r}\n"
+                f"    A {a.output}\n    B {b.output}")
         fail("tokens differ between A and B")
     if d_pre or d_dec:
         fail("logits differ between A and B")
@@ -227,7 +248,7 @@ def one_chip(seed: int, cfg=None) -> None:
 
     dev = jax.devices()[0]
     clock = CompileClock()
-    watch_memory(dev)
+    watch_memory(dev, clock)
     cfg, reduced = smoke_config(cfg)
     m, a = cfg.moe, cfg.attn
     say(f"config: {cfg.name} d_model={cfg.d_model} heads={a.n_heads}/"
@@ -283,8 +304,9 @@ def one_chip(seed: int, cfg=None) -> None:
         f"{st['h2d_rows']} bytes={st['h2d_bytes']} | prefill H2D rows="
         f"{st['prefill_fetch_rows']} bytes={st['prefill_h2d_bytes']} "
         f"waves={st['prefill_waves']} | fallback rows="
-        f"{st['fallback_rows']} fetches={st['fallback_fetches']} | "
-        f"device {mem(dev)}")
+        f"{st['fallback_rows']} fetches={st['fallback_fetches']} | host "
+        f"seconds stage={st['stage_s']:.1f} commit={st['commit_s']:.1f} "
+        f"prefill gathers={st['prefill_stage_s']:.1f} | device {mem(dev)}")
     if len(got) != N_REQUESTS:
         fail(f"phase B served {len(got)} of {N_REQUESTS} requests")
     if st["h2d_rows"] + st["prefill_fetch_rows"] + st["fallback_rows"] == 0:

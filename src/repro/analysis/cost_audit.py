@@ -44,7 +44,7 @@ def stage_h2d_bytes(store, q: int = 2) -> Dict[str, float]:
     sds = jax.ShapeDtypeStruct
     args = (sds((store._buf_cap, d, f), dt), sds((store._buf_cap, d, f), dt),
             sds((store._buf_cap, f, d), dt), sds((q,), jnp.int32),
-            sds((3, q, d * f), dt), sds((L, S + E), jnp.int32))
+            (sds((q, d, f), dt), sds((q, d, f), dt), sds((q, f, d), dt)), sds((L, S + E), jnp.int32))
     jitted = jax.jit(functools.partial(store._stage_inj, S=S),
                      donate_argnums=(0, 1, 2))
     hlo = jitted.lower(*args).compile().as_text()

@@ -257,7 +257,7 @@ def build_entry_points(rs, rungs: Optional[Tuple[str, ...]] = None,
     admission prefill, the admit scatter, the store's three streaming
     jits (with their donation contracts), and the policy ``step``."""
     from repro.models.model import init_caches
-    from repro.serving.steps import make_admit_step
+    from repro.serving.steps import ADMIT_KEYS, make_admit_step
 
     spec = rs.spec
     cfg = spec.cfg
@@ -302,8 +302,8 @@ def build_entry_points(rs, rungs: Optional[Tuple[str, ...]] = None,
     first_tok = jnp.zeros((1, 1), jnp.int32)
     entries.append(EntryPoint(
         name="admit_step", fn=make_admit_step(cfg),
-        args=(state, caches1, first_tok, jnp.asarray(0, jnp.int32),
-              length)))
+        args=({k: state[k] for k in ADMIT_KEYS}, caches1, first_tok,
+              jnp.asarray(0, jnp.int32), length)))
 
     # the store's streaming jits: the donation contract (silent copy
     # fallback here is exactly the O(pool)-copy regression the audit
@@ -331,7 +331,8 @@ def build_entry_points(rs, rungs: Optional[Tuple[str, ...]] = None,
             fn=functools.partial(store._stage_inj, S=S),
             args=(sds((Bc, d, f), dt), sds((Bc, d, f), dt),
                   sds((Bc, f, d), dt), sds((Q,), jnp.int32),
-                  sds((3, Q, d * f), dt), sds((L, S + E), jnp.int32)),
+                  (sds((Q, d, f), dt), sds((Q, d, f), dt),
+                   sds((Q, f, d), dt)), sds((L, S + E), jnp.int32)),
             contract=GraphContract(donate=(0, 1, 2)),
             check_consts=False))
         F = 2

@@ -195,13 +195,21 @@ def _grouped_ffn_rows(xf, wg, wu, wd, cfg: ModelConfig):
     wg/wu (T·K, d, f), wd (T·K, f, d) -> ys (T·K, d).  The single
     contraction body shared by the full-resident sparse path and the
     slot-pool path — byte-identical weight rows therefore produce
-    bit-identical outputs whichever store they were gathered from."""
+    bit-identical outputs whichever store they were gathered from.
+
+    Operands and result pass an optimization barrier.  Without it XLA
+    fuses each caller's own weight producer (a stack gather; a pool
+    gather under inject/fetch selects) into the contractions, and on TPU
+    the fused reduction order, hence the bf16 rounding, follows the
+    producer: the two paths then differ in the last bits."""
+    xf, wg, wu, wd = jax.lax.optimization_barrier((xf, wg, wu, wd))
     K = wg.shape[0] // xf.shape[0]
     xs = jnp.repeat(xf, K, axis=0)                 # (T*K, d)
     act = _ACTS[cfg.act]
     h = act(jnp.einsum("td,tdf->tf", xs, wg)) \
         * jnp.einsum("td,tdf->tf", xs, wu)
-    return jnp.einsum("tf,tfd->td", h, wd)         # (T*K, d)
+    ys = jnp.einsum("tf,tfd->td", h, wd)           # (T*K, d)
+    return jax.lax.optimization_barrier(ys)
 
 
 def _combine_topk(ys, gates):

@@ -186,11 +186,8 @@ def _staged_checksum(sg, su, sd):
 
 @jax.jit
 def _rowsbuf_checksum(rowsbuf):
-    """(Q,) uint32 per-row checksum of a packed (3, Q, d*f) rows buffer."""
-    bits = jnp.uint16 if rowsbuf.dtype.itemsize == 2 else jnp.uint32
-    v = jax.lax.bitcast_convert_type(rowsbuf, bits)
-    flat = v.reshape(3, rowsbuf.shape[1], -1).astype(jnp.uint32)
-    return jax.lax.reduce(flat, np.uint32(0), jax.lax.bitwise_xor, (0, 2))
+    """(Q,) uint32 per-row checksum of a (gate, up, down) rows triple."""
+    return _staged_checksum(*rowsbuf)
 
 
 def moe_layer_layout(cfg: ModelConfig):
@@ -804,15 +801,26 @@ class ExpertStore:
         miss = ~np.asarray(hit)
         rows = np.nonzero(miss)[0]
         self._guard_transient("fetch")   # injected read errors retry here
-        g = np.zeros((e.shape[0], self.d, self.f), self.dtype)
-        u = np.zeros_like(g)
-        dn = np.zeros((e.shape[0], self.f, self.d), self.dtype)
-        g[rows] = self.host["gate"][l, e[rows]]
-        u[rows] = self.host["up"][l, e[rows]]
-        dn[rows] = self.host["down"][l, e[rows]]
+        src = {r: e[r] for r in rows}
+        g, u, dn = (self._gather_rows(self.host[k][l], src, e.shape[0])
+                    for k in ("gate", "up", "down"))
         self._bump("fallback_rows", len(rows))
         self._bump("fallback_fetches", len(set(e[rows].tolist())))
         return g, u, dn
+
+    @staticmethod
+    def _gather_rows(layer, src, n):
+        """(n, ...) stack whose row r is ``layer[src[r]]`` where ``src``
+        names one, zero elsewhere: one copy per shipped row (fancy
+        indexing would copy twice, and zero-filling first writes each
+        byte once more; at full width these rows are hundreds of MB)."""
+        out = np.empty((n,) + layer.shape[1:], layer.dtype)
+        for r in range(n):
+            if r in src:
+                out[r] = layer[src[r]]
+            else:
+                out[r] = 0
+        return out
 
     def host_ffn_cb(self, lid, xf, flat_e, hit):
         """pure_callback target: run missing (token, k) slots' expert FFN
@@ -860,12 +868,9 @@ class ExpertStore:
         ids = np.nonzero(rows >= 0)[0]
         self._guard_transient("prefill-fetch")
         P = self.prefill_rows
-        g = np.zeros((P, self.d, self.f), self.dtype)
-        u = np.zeros_like(g)
-        dn = np.zeros((P, self.f, self.d), self.dtype)
-        g[rows[ids]] = self.host["gate"][l, ids]
-        u[rows[ids]] = self.host["up"][l, ids]
-        dn[rows[ids]] = self.host["down"][l, ids]
+        src = {int(rows[i]): i for i in ids}
+        g, u, dn = (self._gather_rows(self.host[k][l], src, P)
+                    for k in ("gate", "up", "down"))
         self._bump("prefill_fetch_rows", len(ids))
         self._bump("prefill_h2d_bytes", P * self.expert_bytes)
         self._bump("prefill_waves", 1)
@@ -957,19 +962,19 @@ class ExpertStore:
         never enter this program, so the per-step cost is O(insert
         rows), not an O(pool) donate/alias round trip.
 
-        The host args are PACKED so each step ships three transfers:
-        ``pos (Q,)`` int32 = global buffer rows of this step's inserts;
-        ``rowsbuf (3, Q, d*f)`` = their gate/up/down weights flattened;
-        ``meta (L, S+E)`` int32 = post-plan ``cur`` | ``inj_of``, split
-        back out in-graph.  Padding rows carry pos = B and drop on
+        The host args: ``pos (Q,)`` int32 = global buffer rows of this
+        step's inserts; ``rowsbuf`` = their (gate (Q, d, f), up (Q, d, f),
+        down (Q, f, d)) weights in the buffers' own shapes (a flattened
+        (Q, d*f) packing made the chip's compiler spend minutes and
+        ~16 GB of host memory on the reshape at Q = 2); ``meta (L, S+E)``
+        int32 = post-plan ``cur`` | ``inj_of``, split back out in-graph.  Padding rows carry pos = B and drop on
         scatter.  Buffer rows not overwritten keep earlier steps'
         weights — the point: unfolded rows ACCUMULATE here until
         ``_fold_inj``."""
-        Q = pos.shape[0]
-        d, f = buf_g.shape[1], buf_g.shape[2]
-        buf_g = buf_g.at[pos].set(rowsbuf[0].reshape(Q, d, f), mode="drop")
-        buf_u = buf_u.at[pos].set(rowsbuf[1].reshape(Q, d, f), mode="drop")
-        buf_d = buf_d.at[pos].set(rowsbuf[2].reshape(Q, f, d), mode="drop")
+        rg, ru, rd = rowsbuf
+        buf_g = buf_g.at[pos].set(rg, mode="drop")
+        buf_u = buf_u.at[pos].set(ru, mode="drop")
+        buf_d = buf_d.at[pos].set(rd, mode="drop")
         return buf_g, buf_u, buf_d, meta[:, :S], meta[:, S:]
 
     @staticmethod
@@ -1016,7 +1021,8 @@ class ExpertStore:
         q = 1
         while True:
             pos = np.full(q, B, np.int32)
-            rowsbuf = np.zeros((3, q, d * f), rdt)
+            rowsbuf = (np.zeros((q, d, f), rdt), np.zeros((q, d, f), rdt),
+                       np.zeros((q, f, d), rdt))
             meta = np.zeros((L, S + self.E), np.int32)
             jax.block_until_ready(self._stage_inj_jit(
                 *bufs(), pos, rowsbuf, meta))
@@ -1114,11 +1120,14 @@ class ExpertStore:
             # (no stack/concat copies on the critical path)
             pos = np.full(Q, B, np.int32)
             pos[:take] = alloc
-            rowsbuf = np.empty((3, Q, self.d * self.f), self.dtype)
-            rowsbuf[:, take:] = 0
+            rowsbuf = tuple(np.empty((Q,) + self.host[k].shape[2:],
+                                     self.dtype)
+                            for k in ("gate", "up", "down"))
             for k, h in enumerate((self.host["gate"], self.host["up"],
                                    self.host["down"])):
-                rowsbuf[k, :take] = h[clr, cee].reshape(take, -1)
+                for i in range(take):
+                    rowsbuf[k][i] = h[clr[i], cee[i]]
+                rowsbuf[k][take:] = 0
             truth = (_row_checksums_np(rowsbuf[0], rowsbuf[1], rowsbuf[2])
                      if self._verify else None)
             if self.injector is not None:
@@ -1134,8 +1143,9 @@ class ExpertStore:
                                                 take, clr, cee)
             if self.watchdog is not None:
                 jax.block_until_ready(rows_dev)
-                self._fault_sleep(rowsbuf.nbytes)
-                self._observe(rowsbuf.nbytes, time.perf_counter() - tc0)
+                nbytes = sum(a.nbytes for a in rowsbuf)
+                self._fault_sleep(nbytes)
+                self._observe(nbytes, time.perf_counter() - tc0)
             buf_g, buf_u, buf_d = self._inject_buffers()
             buf_g, buf_u, buf_d, cur_d, inj_of_d = self._stage_inj_jit(
                 buf_g, buf_u, buf_d, pos, rows_dev, meta)
@@ -1159,7 +1169,7 @@ class ExpertStore:
         self._bump("corrupt_caught", len(bad))
         for k, h in enumerate((self.host["gate"], self.host["up"],
                                self.host["down"])):
-            rowsbuf[k, bad] = h[clr[bad], cee[bad]].reshape(len(bad), -1)
+            rowsbuf[k][bad] = h[clr[bad], cee[bad]]
         self._bump("restaged_rows", len(bad))
         return jax.device_put(rowsbuf)
 

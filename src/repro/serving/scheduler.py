@@ -74,7 +74,7 @@ from repro.models.config import ModelConfig
 from repro.models.model import init_caches
 from repro.serving.spec import (ResolvedServe, ServeSpec,
                                 build_store, warn_legacy)
-from repro.serving.steps import make_admit_step, retire_slot
+from repro.serving.steps import ADMIT_KEYS, make_admit_step, retire_slot
 
 
 def make_store(offload: str, params, cfg, policy, fallback: str = "fetch",
@@ -346,9 +346,9 @@ class ContinuousBatchServer:
             first_tok, fresh, logits = self._prefill(
                 self.params, jnp.asarray(toks), self._fresh_caches,
                 jnp.asarray(L, jnp.int32))
-        state = self._admit(state, fresh, first_tok,
-                            jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(L, jnp.int32))
+        state = dict(state, **self._admit(
+            {k: state[k] for k in ADMIT_KEYS}, fresh, first_tok,
+            jnp.asarray(slot, jnp.int32), jnp.asarray(L, jnp.int32)))
         jax.block_until_ready(state["tokens"])
         t1 = time.perf_counter()
         self.metrics.prefill_s += t1 - t0

@@ -169,6 +169,10 @@ def make_admit_prefill(cfg: ModelConfig,
     return prefill
 
 
+# the serve-state entries an admission rewrites (see make_admit_step)
+ADMIT_KEYS = ("caches", "tokens", "pos", "active")
+
+
 def make_admit_step(cfg: ModelConfig):
     """Returns admit(state, fresh_caches, first_tok, slot, length) -> state'
     inserting a freshly-prefilled request (B=1 caches) into batch ``slot``.
@@ -178,7 +182,11 @@ def make_admit_step(cfg: ModelConfig):
     is the super-block).  ``pos`` rows are re-masked so cache slots holding
     right-pad garbage (absolute position >= length) read as empty (-1) —
     future decode masks then never attend to them.  ``slot`` and ``length``
-    are traced, so one compilation serves every admission."""
+    are traced, so one compilation serves every admission.
+
+    Jitted callers pass only the ``ADMIT_KEYS`` entries of the serve state
+    and merge the result back: a jitted call copies every output it passes
+    through unchanged, and ``state["offload"]`` holds the whole slot pool."""
 
     def admit(state, fresh_caches, first_tok, slot, length):
         def ins(path, big, small):

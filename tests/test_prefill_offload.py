@@ -246,6 +246,27 @@ def test_server_e2e_stripped_params_matches_modeled(model):
         assert out == ref, mode
 
 
+@pytest.mark.parametrize("mode", PHYSICAL)
+def test_admission_keeps_pool_buffers(model, mode):
+    """An admission hands the slot pool through untouched: the pool never
+    enters the admit jit, whose pass-through outputs would be copies (at
+    full width one more pool's worth of device memory per admission)."""
+    from repro.serving.scheduler import Request
+    cfg, params = model
+    server = _resolve(cfg, params, mode).server()
+    state = server._resolved.init_state(per_slot=True)
+    prompt = np.random.default_rng(29).integers(1, cfg.vocab, 9)
+    new = server._admit_request(
+        state, Request(rid=0, prompt=prompt.astype(np.int32),
+                       max_new_tokens=2), slot=1)
+    old_leaves = jax.tree_util.tree_leaves(state["offload"])
+    new_leaves = jax.tree_util.tree_leaves(new["offload"])
+    assert len(old_leaves) == len(new_leaves)
+    assert all(a is b for a, b in zip(old_leaves, new_leaves))
+    assert bool(new["active"][1]) and not bool(new["active"][0])
+    assert int(new["pos"][1]) == len(prompt)
+
+
 def test_server_sliding_window_exact_admissions(model):
     """sliding_window < max_len forces exact-length admission prefills
     (no bucket padding — right-pad would evict real prompt tokens from
