@@ -126,8 +126,8 @@ def main():
             if store is not None:
                 target = store.next_target(state, tel)
         us = (time.perf_counter() - t0) / steps * 1e6
-        mb = store.h2d_bytes / 1e6 if store is not None else 0.0
-        miss = store.fallback_rows if store is not None else 0
+        mb = store.stats()["h2d_bytes"] / 1e6 if store is not None else 0.0
+        miss = store.stats()["fallback_rows"] if store is not None else 0
         print(f"{mode:26s} {us:12.0f} {mb:12.2f} {miss:10d}")
 
 

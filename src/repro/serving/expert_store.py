@@ -118,6 +118,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.cost_model import CostModel
 from repro.models.config import ModelConfig, scan_pattern
@@ -364,8 +365,7 @@ class ExpertStore:
         # telemetry: a single lock-guarded counter dict.  pure_callback
         # targets (fetch_weights_cb / host_ffn_cb) mutate counters from
         # the runtime's callback thread, so every bump goes through
-        # _bump(); the legacy attribute names (store.h2d_rows, ...) stay
-        # readable as properties.  stats() returns monotonic totals
+        # _bump().  stats() is the one read path: monotonic totals
         # (benchmarks snapshot-diff them); drain() returns the deltas
         # since the last drain and resets that baseline.
         self._tel_lock = threading.Lock()
@@ -374,8 +374,10 @@ class ExpertStore:
             "fallback_fetches": 0,   # experts demand-fetched
             "h2d_rows": 0,           # experts streamed into the pool
             "h2d_bytes": 0,
+            "fetch_s": 0.0,          # host time inside fetch_weights_cb
+            "fetch_bytes": 0,        # bytes it returns, hit rows included
             "stage_s": 0.0,          # host time in stage()/inject build
-            "commit_s": 0.0,         # host time in commit dispatch/wait
+            "commit_s": 0.0,         # host time in commit()/inject fold
             "retries": 0,            # transient-fault retries that fired
             "stalls": 0,             # injected stage stalls hit
             "read_errors": 0,        # injected host read errors hit
@@ -795,17 +797,24 @@ class ExpertStore:
         """pure_callback target: demand-fetch missing experts' weights.
         Returns (T·K, d, f)/(T·K, f, d) stacks with miss rows filled from
         the host store (hit rows are zeros — the caller keeps its pool
-        gather for those)."""
+        gather for those).  ``fetch_s`` books the host time spent here;
+        the runtime's copy of the result to the device comes after."""
+        t0 = time.perf_counter()
         l = int(lid)
         e = np.asarray(flat_e)
         miss = ~np.asarray(hit)
         rows = np.nonzero(miss)[0]
-        self._guard_transient("fetch")   # injected read errors retry here
-        src = {r: e[r] for r in rows}
-        g, u, dn = (self._gather_rows(self.host[k][l], src, e.shape[0])
-                    for k in ("gate", "up", "down"))
+        nbytes = e.shape[0] * self.expert_bytes
+        with TraceAnnotation("dali:store.fetch_weights", layer=l,
+                             miss_rows=len(rows), bytes=nbytes):
+            self._guard_transient("fetch")   # injected read errors retry
+            src = {r: e[r] for r in rows}
+            g, u, dn = (self._gather_rows(self.host[k][l], src, e.shape[0])
+                        for k in ("gate", "up", "down"))
         self._bump("fallback_rows", len(rows))
         self._bump("fallback_fetches", len(set(e[rows].tolist())))
+        self._bump("fetch_bytes", nbytes)
+        self._bump("fetch_s", time.perf_counter() - t0)
         return g, u, dn
 
     @staticmethod
@@ -832,13 +841,16 @@ class ExpertStore:
         K = e.shape[0] // xf.shape[0]
         ys = np.zeros((e.shape[0], self.d), xf.dtype)
         rows = np.nonzero(~np.asarray(hit))[0]
-        self._guard_transient("host-ffn")
-        for r in rows:
-            x = xf[r // K].astype(np.float32)
-            wg = self.host["gate"][l, e[r]].astype(np.float32)
-            wu = self.host["up"][l, e[r]].astype(np.float32)
-            wd = self.host["down"][l, e[r]].astype(np.float32)
-            ys[r] = ((self._act(x @ wg) * (x @ wu)) @ wd).astype(ys.dtype)
+        with TraceAnnotation("dali:store.host_ffn", layer=l,
+                             miss_rows=len(rows)):
+            self._guard_transient("host-ffn")
+            for r in rows:
+                x = xf[r // K].astype(np.float32)
+                wg = self.host["gate"][l, e[r]].astype(np.float32)
+                wu = self.host["up"][l, e[r]].astype(np.float32)
+                wd = self.host["down"][l, e[r]].astype(np.float32)
+                ys[r] = ((self._act(x @ wg) * (x @ wu)) @ wd).astype(
+                    ys.dtype)
         self._bump("fallback_rows", len(rows))
         return ys
 
@@ -866,11 +878,13 @@ class ExpertStore:
         l = int(lid)
         rows = np.asarray(rows)
         ids = np.nonzero(rows >= 0)[0]
-        self._guard_transient("prefill-fetch")
         P = self.prefill_rows
-        src = {int(rows[i]): i for i in ids}
-        g, u, dn = (self._gather_rows(self.host[k][l], src, P)
-                    for k in ("gate", "up", "down"))
+        with TraceAnnotation("dali:store.prefill_fetch", layer=l,
+                             rows=len(ids), bytes=P * self.expert_bytes):
+            self._guard_transient("prefill-fetch")
+            src = {int(rows[i]): i for i in ids}
+            g, u, dn = (self._gather_rows(self.host[k][l], src, P)
+                        for k in ("gate", "up", "down"))
         self._bump("prefill_fetch_rows", len(ids))
         self._bump("prefill_h2d_bytes", P * self.expert_bytes)
         self._bump("prefill_waves", 1)
@@ -894,13 +908,16 @@ class ExpertStore:
         K = e.shape[0] // xf.shape[0]
         ys = np.zeros((e.shape[0], self.d), xf.dtype)
         rows = np.nonzero(~np.asarray(hit))[0]
-        self._guard_transient("prefill-host")
-        for r in rows:
-            x = xf[r // K].astype(np.float32)
-            wg = self.host["gate"][l, e[r]].astype(np.float32)
-            wu = self.host["up"][l, e[r]].astype(np.float32)
-            wd = self.host["down"][l, e[r]].astype(np.float32)
-            ys[r] = ((self._act(x @ wg) * (x @ wu)) @ wd).astype(ys.dtype)
+        with TraceAnnotation("dali:store.prefill_host", layer=l,
+                             miss_rows=len(rows)):
+            self._guard_transient("prefill-host")
+            for r in rows:
+                x = xf[r // K].astype(np.float32)
+                wg = self.host["gate"][l, e[r]].astype(np.float32)
+                wu = self.host["up"][l, e[r]].astype(np.float32)
+                wd = self.host["down"][l, e[r]].astype(np.float32)
+                ys[r] = ((self._act(x @ wg) * (x @ wu)) @ wd).astype(
+                    ys.dtype)
         self._bump("prefill_host_rows", len(rows))
         self._bump("fallback_rows", len(rows))
         self._bump("prefill_stage_s", time.perf_counter() - t0)
@@ -1051,6 +1068,7 @@ class ExpertStore:
                 "inj_of": jax.device_put(self._inj_of()),
                 "cur": jax.device_put(self._cur.copy())}
 
+    @functools.partial(annotate_function, name="dali:store.stage")
     def _pipeline_pre_step(self, off, target):
         """Pipelined ``pre_step``: plan toward ``target`` against the
         host mirror, gather ONLY the valid insert rows — a compact
@@ -1101,7 +1119,9 @@ class ExpertStore:
         while done < n:
             room = B - len(self._live)
             if room <= 0:
+                tf = time.perf_counter()
                 off = self._fold_live(off)
+                t0 += time.perf_counter() - tf   # booked under commit_s
                 room = B
             take = min(room, n - done)
             sl = slice(done, done + take)
@@ -1180,6 +1200,7 @@ class ExpertStore:
             inj_of[l, e] = r
         return inj_of
 
+    @functools.partial(annotate_function, name="dali:store.fold")
     def _fold_live(self, off):
         """Scatter every live unfolded buffer row into the (donated)
         pool and clear the ledger — the pipelined commit point.  Rows
@@ -1215,6 +1236,7 @@ class ExpertStore:
         fewer rows per step."""
         return lower_slot_plan_np(self._cur, target, self._effective_moves())
 
+    @functools.partial(annotate_function, name="dali:store.stage")
     def stage(self, target) -> bool:
         """Plan one step's pool update toward ``target`` (L, E) bool (the
         policy's cache ∪ prefetch for the next step) and issue the
@@ -1301,6 +1323,7 @@ class ExpertStore:
         self._bump("stage_s", time.perf_counter() - t0)
         return True
 
+    @functools.partial(annotate_function, name="dali:store.commit")
     def commit(self, off, blocking: bool = False):
         """Fold the staged rows into the spare pool generation (donated,
         in-place scatter — O(rows), no pool copy) and return it as the
@@ -1353,6 +1376,7 @@ class ExpertStore:
     # be read after the token sync) — both servers, the streaming
     # benchmark and the example drive these three hooks.
 
+    @functools.partial(annotate_function, name="dali:store.pre_step")
     def pre_step(self, off, mode: str, target):
         """Before the decode dispatch: "blocking" → stage + commit +
         wait (the whole copy on the critical path); "overlap" → commit
@@ -1384,26 +1408,12 @@ class ExpertStore:
             self.stage(target)
 
     @staticmethod
+    @functools.partial(annotate_function, name="dali:store.next_target")
     def next_target(state, tel):
         """The next step's pool target — this step's cache ∪ prefetch
         (tiny D2H; call after the step's token sync so it never blocks)."""
         return (np.asarray(state["dali"]["resident"])
                 | np.asarray(tel["prefetched"]))
-
-
-def _counter_property(name):
-    def get(self):
-        with self._tel_lock:
-            return self._tel[name]
-    get.__doc__ = f"Legacy read-only alias for stats()['{name}']."
-    return property(get)
-
-
-# the pre-drain attribute names stay readable (tests/benchmarks use them)
-for _n in ("fallback_rows", "fallback_fetches", "h2d_rows", "h2d_bytes",
-           "stage_s", "commit_s"):
-    setattr(ExpertStore, _n, _counter_property(_n))
-del _n
 
 
 # declare the host<->device seams this store exposes to serving graphs:

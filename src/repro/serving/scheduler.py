@@ -60,6 +60,7 @@ and both report per-request latency and TTFT.
 """
 from __future__ import annotations
 
+import itertools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -68,6 +69,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import DaliConfig, TelemetryAggregator
 from repro.models.config import ModelConfig
@@ -169,23 +171,6 @@ class ServeMetrics:
         if not self.requests:
             return 0.0
         return self.offload_tel.get("fallback_rows", 0) / self.requests
-
-    # -- legacy accessors (pre-refactor field names) -----------------------
-    @property
-    def dali_moe_time_est(self) -> float:
-        return self.dali.moe_time_est
-
-    @property
-    def dali_link_time_est(self) -> float:
-        return self.dali.link_time_est
-
-    @property
-    def dali_hits(self) -> int:
-        return self.dali.hits
-
-    @property
-    def dali_lookups(self) -> int:
-        return self.dali.lookups
 
     def mean_occupancy(self) -> float:
         return self.occupancy_sum / self.steps if self.steps else 0.0
@@ -372,71 +357,86 @@ class ContinuousBatchServer:
         # pending lowering to a slot plan (double-buffer lag of one step)
         pool_target = None
 
-        while self.queue or any(slot_req):
-            now = time.perf_counter()
-            # -- admission: fill freed slots from the queue ----------------
-            for slot in range(B):
-                if slot_req[slot] is not None:
+        # host spans (``dali:serve.*``): one ``step`` per pass of the loop,
+        # its children name the calls out of the scheduler's own code
+        for step in itertools.count():
+            if not (self.queue or any(slot_req)):
+                break
+            live = sum(r is not None for r in slot_req)
+            with TraceAnnotation("dali:serve.step", step=step, live=live):
+                now = time.perf_counter()
+                # -- admission: fill freed slots from the queue ------------
+                for slot in range(B):
+                    if slot_req[slot] is not None:
+                        continue
+                    req = _pop_arrived(self.queue, now)
+                    if req is None:
+                        break
+                    with TraceAnnotation("dali:serve.admit", rid=req.rid,
+                                         slot=slot,
+                                         prompt_tokens=len(req.prompt)):
+                        state = self._admit_request(state, req, slot)
+                    if self._should_retire(req):     # EOS on first token
+                        with TraceAnnotation("dali:serve.retire"):
+                            req.done_at = req.first_token_at
+                            finished.append(req)
+                            state = retire_slot(state, slot)
+                    else:
+                        slot_req[slot] = req
+
+                busy = [i for i in range(B) if slot_req[i] is not None]
+                if not busy:
+                    if not self.queue:
+                        break
+                    with TraceAnnotation("dali:serve.wait_arrival"):
+                        time.sleep(max(0.0, self.queue[0].not_before
+                                       - time.perf_counter()))
                     continue
-                req = _pop_arrived(self.queue, now)
-                if req is None:
-                    break
-                state = self._admit_request(state, req, slot)
-                if self._should_retire(req):         # EOS on first token
-                    req.done_at = req.first_token_at
-                    finished.append(req)
-                    state = retire_slot(state, slot)
-                else:
-                    slot_req[slot] = req
 
-            busy = [i for i in range(B) if slot_req[i] is not None]
-            if not busy:
-                if not self.queue:
-                    break
-                time.sleep(max(0.0,
-                               self.queue[0].not_before - time.perf_counter()))
-                continue
+                # -- one decode step over the whole slot table -------------
+                # (physical offload: the store's pre_step/post_dispatch/
+                # next_target hooks schedule the pool streaming around the
+                # dispatch — see expert_store.py, DESIGN.md §8)
+                t0 = time.perf_counter()
+                with TraceAnnotation("dali:serve.decode", step=step,
+                                     live=len(busy)):
+                    if self.store is not None:
+                        state["offload"] = self.store.pre_step(
+                            state["offload"], self.offload, pool_target)
+                        self._decode.react()  # follow the degradation ladder
+                    state, logits, tel = self._decode(self.params, state,
+                                                      self.res_vecs)
+                    if self.store is not None:
+                        self.store.post_dispatch(self.offload, pool_target)
+                with TraceAnnotation("dali:serve.tokens", step=step):
+                    toks = np.asarray(state["tokens"])[:, 0]
+                t1 = time.perf_counter()
+                _record_logits([slot_req[i] for i in busy], busy, logits)
+                if self.store is not None:
+                    pool_target = self.store.next_target(state, tel)
 
-            # -- one decode step over the whole slot table -----------------
-            # (physical offload: the store's pre_step/post_dispatch/
-            # next_target hooks schedule the pool streaming around the
-            # dispatch — see expert_store.py, DESIGN.md §8)
-            t0 = time.perf_counter()
-            if self.store is not None:
-                state["offload"] = self.store.pre_step(
-                    state["offload"], self.offload, pool_target)
-                self._decode.react()     # follow the degradation ladder
-            state, logits, tel = self._decode(self.params, state,
-                                              self.res_vecs)
-            if self.store is not None:
-                self.store.post_dispatch(self.offload, pool_target)
-            toks = np.asarray(state["tokens"])[:, 0]
-            t1 = time.perf_counter()
-            _record_logits([slot_req[i] for i in busy], busy, logits)
-            if self.store is not None:
-                pool_target = self.store.next_target(state, tel)
-
-            # single per-slot "emitted this step" count: every live slot
-            # contributes exactly one token (no re-derivation, no double
-            # counting of a request's final token)
-            emitted = len(busy)
-            for i in busy:
-                r = slot_req[i]
-                r.output.append(int(toks[i]))
-                if self._should_retire(r):
-                    r.done_at = t1
-                    finished.append(r)
-                    slot_req[i] = None
-                    state = retire_slot(state, i)
-            self.metrics.decode_tokens += emitted
-            self.metrics.decode_s += t1 - t0
-            self.metrics.steps += 1
-            self.metrics.occupancy_sum += emitted
-            if self.store is not None:
-                self.metrics.fold_offload(self.store.drain())
-            # sync-free: telemetry accumulates on device, drained on the
-            # aggregator's flush interval (and below, at retirement)
-            self.metrics.dali.observe(state.get("dali"), n_active=emitted)
+                # single per-slot "emitted this step" count: every live
+                # slot contributes exactly one token (no re-derivation, no
+                # double counting of a request's final token)
+                emitted = len(busy)
+                with TraceAnnotation("dali:serve.retire"):
+                    for i in busy:
+                        r = slot_req[i]
+                        r.output.append(int(toks[i]))
+                        if self._should_retire(r):
+                            r.done_at = t1
+                            finished.append(r)
+                            slot_req[i] = None
+                            state = retire_slot(state, i)
+                self.metrics.decode_tokens += emitted
+                self.metrics.decode_s += t1 - t0
+                self.metrics.steps += 1
+                self.metrics.occupancy_sum += emitted
+                if self.store is not None:
+                    self.metrics.fold_offload(self.store.drain())
+                # sync-free: telemetry accumulates on device, drained on
+                # the aggregator's flush interval (and below, at retirement)
+                self.metrics.dali.observe(state.get("dali"), n_active=emitted)
         self.metrics.dali.end_epoch()
         if self.store is not None:
             self.metrics.fold_offload(self.store.drain())

@@ -109,15 +109,15 @@ def test_slot_decode_bit_identical(model, kind):
         np.testing.assert_array_equal(ref, slot, err_msg=f"step {i}")
     # the pool is smaller than the working set, so the fallback tier must
     # actually have served misses for the parity above to mean anything
-    assert store.fallback_rows > 0
-    assert store.h2d_rows > 0
+    assert store.stats()["fallback_rows"] > 0
+    assert store.stats()["h2d_rows"] > 0
 
 
 def test_forced_miss_step_hits_host_fallback_bitwise(model):
     cfg, params = model
     pairs, store = _run_pair(cfg, params, "uniform", n_steps=5,
                              force_miss_at=2)
-    before = store.fallback_rows
+    before = store.stats()["fallback_rows"]
     assert before > 0          # the emptied pool forced demand fetches
     for i, (ref, slot) in enumerate(pairs):
         np.testing.assert_array_equal(ref, slot, err_msg=f"step {i}")
@@ -131,7 +131,7 @@ def test_host_ffn_fallback_close_and_exercised(model):
     cfg, params = model
     pairs, store = _run_pair(cfg, params, "uniform", n_steps=5,
                              fallback="host", force_miss_at=1)
-    assert store.fallback_rows > 0
+    assert store.stats()["fallback_rows"] > 0
     for i, (ref, slot) in enumerate(pairs):
         np.testing.assert_allclose(ref, slot, rtol=2e-4, atol=2e-4,
                                    err_msg=f"step {i}")
@@ -158,7 +158,36 @@ def test_dead_slots_do_not_trigger_fallback(model):
     state, _, _ = dec(strip_expert_params(params, cfg), state)
     jax.block_until_ready(state["tokens"])
     live_rows = 1 * cfg.moe.top_k * store.n_layers      # one live slot
-    assert 0 < store.fallback_rows <= live_rows
+    assert 0 < store.stats()["fallback_rows"] <= live_rows
+
+
+def test_fetch_counters_book_host_seconds_and_returned_bytes(model):
+    """An emptied pool makes every MoE layer's decode call the fetch
+    seam once; each call returns all T·K rows (hit rows as zeros), so
+    ``fetch_bytes`` counts calls × T·K × expert_bytes and ``fetch_s``
+    the host time spent inside the callback."""
+    cfg, params = model
+    pol = resolve_policy("dali", cfg)
+    store = ExpertStore(params, cfg,
+                        n_slots=pol.dcfg.cache_size + pol.dcfg.prefetch_size)
+    dec = jax.jit(make_decode_step(cfg, policy=pol, offload=store))
+    B = 2
+    state = init_serve_state(cfg, B, 32, policy=pol, per_slot=True,
+                             offload=store)
+    state["active"] = jnp.ones((B,), bool)
+    state["offload"] = dict(state["offload"],
+                            cur=jnp.full_like(state["offload"]["cur"], -1))
+    store._cur[:] = -1
+    before = store.stats()
+    assert before["fetch_s"] == 0.0 and before["fetch_bytes"] == 0
+    state, _, _ = dec(strip_expert_params(params, cfg), state)
+    jax.block_until_ready(state["tokens"])
+    st = store.stats()
+    calls = store.n_layers
+    assert st["fetch_bytes"] == (calls * B * cfg.moe.top_k
+                                 * store.expert_bytes)
+    assert st["fetch_s"] > 0.0
+    assert st["fallback_rows"] == calls * B * cfg.moe.top_k
 
 
 def test_bad_fallback_rejected(model):
@@ -281,7 +310,7 @@ def test_server_outputs_identical_across_offload_modes(model):
         done = srv.run()
         outs[mode] = [r.output for r in sorted(done, key=lambda r: r.rid)]
         if mode != "modeled":
-            assert srv.store.h2d_rows > 0
+            assert srv.store.stats()["h2d_rows"] > 0
     assert (outs["modeled"] == outs["blocking"] == outs["overlap"]
             == outs["pipelined"])
 
@@ -356,17 +385,17 @@ def test_pipelined_decode_bit_identical(model, kind):
     for i, (ref, slot) in enumerate(pairs):
         np.testing.assert_array_equal(ref, slot, err_msg=f"step {i}")
     # misses + streaming both happened, so the parity is load-bearing
-    assert store.fallback_rows > 0
-    assert store.h2d_rows > 0
+    assert store.stats()["fallback_rows"] > 0
+    assert store.stats()["h2d_rows"] > 0
     # the fold + stage run as one fused dispatch timed under stage_s
-    assert store.stage_s > 0.0
+    assert store.stats()["stage_s"] > 0.0
 
 
 def test_pipelined_forced_miss_mid_trace_bitwise(model):
     cfg, params = model
     pairs, store = _run_hooked(cfg, params, "pipelined", "uniform",
                                n_steps=6, force_miss_at=3)
-    assert store.fallback_rows > 0
+    assert store.stats()["fallback_rows"] > 0
     for i, (ref, slot) in enumerate(pairs):
         np.testing.assert_array_equal(ref, slot, err_msg=f"step {i}")
 
@@ -386,7 +415,7 @@ def test_pipelined_matches_boundary_commit_modes(model):
         np.testing.assert_array_equal(
             runs["pipelined"][0][i][1], runs["overlap"][0][i][1],
             err_msg=f"pipelined vs overlap, step {i}")
-    miss = {m: st.fallback_rows for m, (_, st) in runs.items()}
+    miss = {m: st.stats()["fallback_rows"] for m, (_, st) in runs.items()}
     assert miss["pipelined"] == miss["blocking"]
     assert miss["pipelined"] <= miss["overlap"]
 

@@ -407,7 +407,6 @@ def test_drain_windows_partition_counters(model):
     store._bump("fallback_rows", 4)
     assert store.drain()["fallback_rows"] == 4
     assert store.stats()["fallback_rows"] == 7
-    assert store.fallback_rows == 7             # legacy attribute view
 
 
 def test_server_reports_fallback_rate(model):

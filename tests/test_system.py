@@ -105,7 +105,7 @@ def test_batch_server_end_to_end(small_moe):
         assert 1 <= len(r.output) <= 8
         assert r.done_at >= r.submitted_at
     assert server.metrics.decode_tokens > 0
-    assert server.metrics.dali_lookups >= 0
+    assert server.metrics.dali.lookups >= 0
 
 
 def test_dali_inapplicable_archs_serve_without_engine():
