@@ -246,9 +246,11 @@ def slot_expert_ffn(slots, slot_fetch, xf, idx, gates, cfg: ModelConfig,
     misses fall back to the host tier via ``slot_fetch`` (an ExpertStore)
     under ``lax.cond`` so fully-resident steps never leave the device:
 
-      * fallback "fetch" — missing experts' weights stream from the host
-        store (pure_callback H2D) and the FFN stays on device, so the
-        output is bit-identical to the full-resident gather;
+      * fallback "fetch" — each distinct missing expert's weights
+        stream once from the host store (one pure_callback H2D per
+        expert, ``_fetch_distinct_misses``) into the (token, k) rows
+        that miss it, and the FFN stays on device, so the output is
+        bit-identical to the full-resident gather;
       * fallback "host" — missing rows' FFN executes on the host (CPU
         tier) and only (d,)-sized outputs cross back;
       * fallback "little" — missing rows read ``slot_little``, the
@@ -330,20 +332,43 @@ def slot_expert_ffn(slots, slot_fetch, xf, idx, gates, cfg: ModelConfig,
             (slots["lid"], xf, flat_e, hit))
         ys = jnp.where(hm, ys, ys_host)
     else:                                          # "fetch"
-        shapes = (jax.ShapeDtypeStruct(wg.shape, wg.dtype),
-                  jax.ShapeDtypeStruct(wu.shape, wu.dtype),
-                  jax.ShapeDtypeStruct(wd.shape, wd.dtype))
-        mg, mu, md = jax.lax.cond(
-            any_miss,
-            lambda a: jax.pure_callback(slot_fetch.fetch_weights_cb,
-                                        shapes, *a),
-            lambda a: tuple(jnp.zeros(s.shape, s.dtype) for s in shapes),
-            (slots["lid"], flat_e, hit))
-        hw = hit[:, None, None]
-        ys = _grouped_ffn_rows(xf, jnp.where(hw, wg, mg),
-                               jnp.where(hw, wu, mu),
-                               jnp.where(hw, wd, md), cfg)
+        wg, wu, wd = _fetch_distinct_misses(
+            slot_fetch, slots["lid"], flat_e, hit,
+            slots["slot_of"].shape[0], (wg, wu, wd))
+        ys = _grouped_ffn_rows(xf, wg, wu, wd, cfg)
     return _combine_topk(ys, gates)
+
+
+def _fetch_distinct_misses(slot_fetch, lid, flat_e, hit, E, rows):
+    """Demand-fetch each distinct missing expert of one layer once and
+    write it into every (token, k) row that misses it.
+
+    ``flat_e``/``hit`` (T·K,) name the rows' experts and whether each is
+    served on device; ``rows`` are the (T·K, ...) gate/up/down weights
+    gathered from the pool (and inject buffers).  The missing experts,
+    ``n_miss`` ≤ min(T·K, E) of them in ascending id order, are fetched
+    by one ``fetch_weights_cb`` call each — a ``fori_loop`` of
+    ``n_miss`` trips under ``lax.cond(n_miss > 0)``, so an all-hit layer
+    never leaves the device — and each lands in its rows of ``rows`` in
+    place: no device buffer beyond the gathered rows, and the bytes a
+    row ends with are those a full-resident gather reads."""
+    miss = ~hit
+    rows_of_e = jnp.zeros((E,), jnp.int32).at[flat_e].add(
+        miss.astype(jnp.int32))
+    n_miss = jnp.sum(rows_of_e > 0)
+    e_at = jnp.nonzero(rows_of_e > 0, size=E, fill_value=0)[0]
+    one = tuple(jax.ShapeDtypeStruct(w.shape[1:], w.dtype) for w in rows)
+
+    def body(i, rows):
+        e = e_at[i]
+        got = jax.pure_callback(slot_fetch.fetch_weights_cb, one,
+                                lid, e, rows_of_e[e])
+        at = (miss & (flat_e == e))[:, None, None]
+        return tuple(jnp.where(at, g[None], w) for g, w in zip(got, rows))
+
+    return jax.lax.cond(n_miss > 0,
+                        lambda r: jax.lax.fori_loop(0, n_miss, body, r),
+                        lambda r: r, tuple(rows))
 
 
 def slot_expert_stacks(slots, slot_fetch, counts, cfg: ModelConfig,

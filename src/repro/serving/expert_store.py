@@ -75,11 +75,14 @@ weights sat in device memory and the OffloadPolicy's ``resident`` /
 Misses — experts a step activates that are not pooled — fall back to the
 host tier:
 
-  * ``fallback="fetch"`` (default): the missing experts' weights are
-    demand-fetched from the host store via ``jax.pure_callback`` (a real
-    host→device transfer on the critical path, the cost the paper's
-    Eq. 5 charges for non-resident GPU execution) and the FFN computes
-    on device — bit-identical to full-resident decode.
+  * ``fallback="fetch"`` (default): each distinct missing expert of a
+    layer is demand-fetched once from the host store via
+    ``jax.pure_callback`` (``fetch_weights_cb`` returns views of the
+    store's blocks, no host copy; a real host→device transfer on the
+    critical path, the cost the paper's Eq. 5 charges for non-resident
+    GPU execution); the device writes each into the (token, k) rows
+    that miss it and the FFN computes on device — bit-identical to
+    full-resident decode.
   * ``fallback="host"``: the missing (token, expert) slots' FFN runs on
     the host (numpy) and only the (d,)-sized outputs cross the link —
     the paper's CPU execution tier.  Host BLAS and XLA round
@@ -375,7 +378,7 @@ class ExpertStore:
             "h2d_rows": 0,           # experts streamed into the pool
             "h2d_bytes": 0,
             "fetch_s": 0.0,          # host time inside fetch_weights_cb
-            "fetch_bytes": 0,        # bytes it returns, hit rows included
+            "fetch_bytes": 0,        # bytes it returns: distinct misses
             "stage_s": 0.0,          # host time in stage()/inject build
             "commit_s": 0.0,         # host time in commit()/inject fold
             "retries": 0,            # transient-fault retries that fired
@@ -793,29 +796,27 @@ class ExpertStore:
 
     # -- miss fallbacks (host callbacks, see module docstring) -------------
 
-    def fetch_weights_cb(self, lid, flat_e, hit):
-        """pure_callback target: demand-fetch missing experts' weights.
-        Returns (T·K, d, f)/(T·K, f, d) stacks with miss rows filled from
-        the host store (hit rows are zeros — the caller keeps its pool
-        gather for those).  ``fetch_s`` books the host time spent here;
-        the runtime's copy of the result to the device comes after."""
+    def fetch_weights_cb(self, lid, expert, miss_rows):
+        """pure_callback target: demand-fetch ONE missing expert.
+
+        ``slot_expert_ffn`` calls it once per distinct missing expert of
+        a layer (``miss_rows`` of that layer's (token, k) rows route to
+        it) and writes it into those rows on device.  Returns the expert's
+        gate/up/down blocks of the host store as they are — contiguous
+        views, no copy; the runtime ships them to the device after this
+        returns.  ``fetch_s`` books the host time spent here and
+        ``fetch_bytes`` the bytes returned."""
         t0 = time.perf_counter()
-        l = int(lid)
-        e = np.asarray(flat_e)
-        miss = ~np.asarray(hit)
-        rows = np.nonzero(miss)[0]
-        nbytes = e.shape[0] * self.expert_bytes
-        with TraceAnnotation("dali:store.fetch_weights", layer=l,
-                             miss_rows=len(rows), bytes=nbytes):
+        l, e, n = int(lid), int(expert), int(miss_rows)
+        with TraceAnnotation("dali:store.fetch_weights", layer=l, expert=e,
+                             miss_rows=n, bytes=self.expert_bytes):
             self._guard_transient("fetch")   # injected read errors retry
-            src = {r: e[r] for r in rows}
-            g, u, dn = (self._gather_rows(self.host[k][l], src, e.shape[0])
-                        for k in ("gate", "up", "down"))
-        self._bump("fallback_rows", len(rows))
-        self._bump("fallback_fetches", len(set(e[rows].tolist())))
-        self._bump("fetch_bytes", nbytes)
+            out = tuple(self.host[k][l, e] for k in ("gate", "up", "down"))
+        self._bump("fallback_rows", n)
+        self._bump("fallback_fetches", 1)
+        self._bump("fetch_bytes", self.expert_bytes)
         self._bump("fetch_s", time.perf_counter() - t0)
-        return g, u, dn
+        return out
 
     @staticmethod
     def _gather_rows(layer, src, n):

@@ -71,6 +71,11 @@ def test_audit_pipelined_all_rungs_pass(params_and_cfg):
         for cb in e["callbacks"]:
             assert cb["seam"] is not None
             assert cb["guarded"]
+    # the full-quality decode demand-fetches its misses through the
+    # registered per-expert seam (inside its guarded loop)
+    seams = {cb["seam"]
+             for cb in by_name["decode[pipelined/healthy]"]["callbacks"]}
+    assert "fetch_weights" in seams
 
 
 def test_audit_cost_checks_pipelined(params_and_cfg):

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, make_smoke
-from repro.models.model import init_model
+from repro.models.model import apply_model, collect_field, init_model
 from repro.serving.expert_store import (ExpertStore, lower_slot_plan,
                                         lower_slot_plan_np,
                                         strip_expert_params)
@@ -161,33 +161,107 @@ def test_dead_slots_do_not_trigger_fallback(model):
     assert 0 < store.stats()["fallback_rows"] <= live_rows
 
 
-def test_fetch_counters_book_host_seconds_and_returned_bytes(model):
-    """An emptied pool makes every MoE layer's decode call the fetch
-    seam once; each call returns all T·K rows (hit rows as zeros), so
-    ``fetch_bytes`` counts calls × T·K × expert_bytes and ``fetch_s``
-    the host time spent inside the callback."""
-    cfg, params = model
+def _fetch_step(cfg, params, tokens, resident=None):
+    """One continuous-batching decode step, full-resident and through a
+    store whose pool holds ``resident`` ((L, E) bools; None = empty, so
+    every activated expert misses), on the same live tokens.  Returns
+    (reference logits, store logits, the store, each layer's routed
+    experts (L, T·K) from the full-resident forward)."""
     pol = resolve_policy("dali", cfg)
     store = ExpertStore(params, cfg,
                         n_slots=pol.dcfg.cache_size + pol.dcfg.prefetch_size)
-    dec = jax.jit(make_decode_step(cfg, policy=pol, offload=store))
+    B = tokens.shape[0]
+    states = []
+    for off in (None, store):
+        st = init_serve_state(cfg, B, 32, policy=pol, per_slot=True,
+                              offload=off)
+        st["active"] = jnp.ones((B,), bool)
+        st["tokens"] = tokens
+        states.append(st)
+    s_ref, s_slot = states
+    if resident is None:
+        resident = np.zeros((store.n_layers, cfg.moe.n_routed), bool)
+    s_slot["offload"] = store.init_device_state(resident)
+    _, _, infos = apply_model(params, tokens, cfg,
+                              positions=s_ref["pos"][:, None],
+                              caches=s_ref["caches"], trace=True)
+    routed = np.asarray(collect_field(infos, "topk_idx")).reshape(
+        store.n_layers, -1)
+    _, lg_ref, _ = jax.jit(make_decode_step(cfg, policy=pol))(params, s_ref)
+    _, lg_slot, _ = jax.jit(make_decode_step(cfg, policy=pol,
+                                             offload=store))(
+        strip_expert_params(params, cfg), s_slot)
+    return np.asarray(lg_ref), np.asarray(lg_slot), store, routed
+
+
+def test_fetch_counters_book_host_seconds_and_returned_bytes(model):
+    """An emptied pool makes every activated expert miss: each MoE layer
+    calls the fetch seam once per DISTINCT routed expert, so
+    ``fetch_bytes`` counts the distinct missing experts × expert_bytes
+    (no zero or duplicate rows), ``fallback_rows`` every (token, k) row
+    and ``fetch_s`` the host time spent inside the callbacks."""
+    cfg, params = model
     B = 2
-    state = init_serve_state(cfg, B, 32, policy=pol, per_slot=True,
-                             offload=store)
-    state["active"] = jnp.ones((B,), bool)
-    state["offload"] = dict(state["offload"],
-                            cur=jnp.full_like(state["offload"]["cur"], -1))
-    store._cur[:] = -1
-    before = store.stats()
-    assert before["fetch_s"] == 0.0 and before["fetch_bytes"] == 0
-    state, _, _ = dec(strip_expert_params(params, cfg), state)
-    jax.block_until_ready(state["tokens"])
+    tokens = jnp.asarray([[7], [11]], jnp.int32)
+    _, _, store, routed = _fetch_step(cfg, params, tokens)
     st = store.stats()
-    calls = store.n_layers
-    assert st["fetch_bytes"] == (calls * B * cfg.moe.top_k
-                                 * store.expert_bytes)
+    distinct = sum(len(set(r.tolist())) for r in routed)
+    # these two tokens share an expert in some layer, so the distinct
+    # count sits below the (token, k) rows
+    assert distinct < store.n_layers * B * cfg.moe.top_k
+    assert st["fallback_fetches"] == distinct
+    assert st["fetch_bytes"] == distinct * store.expert_bytes
     assert st["fetch_s"] > 0.0
-    assert st["fallback_rows"] == calls * B * cfg.moe.top_k
+    assert st["fallback_rows"] == store.n_layers * B * cfg.moe.top_k
+
+
+def test_shared_missing_expert_fetched_once_bitwise(model):
+    """Both live tokens are the same token at the same position, so every
+    layer routes them to the same experts: each missing expert is
+    shipped once for its two rows, and decode stays bit-equal to
+    full-resident decode."""
+    cfg, params = model
+    B, K = 2, cfg.moe.top_k
+    lg_ref, lg_slot, store, routed = _fetch_step(
+        cfg, params, jnp.full((B, 1), 17, jnp.int32))
+    st = store.stats()
+    distinct = sum(len(set(r.tolist())) for r in routed)
+    assert distinct == store.n_layers * K        # every row shared
+    assert st["fallback_fetches"] == distinct
+    assert st["fetch_bytes"] == distinct * store.expert_bytes
+    assert st["fallback_rows"] == store.n_layers * B * K
+    np.testing.assert_array_equal(lg_ref, lg_slot)
+
+
+def test_all_hit_step_makes_no_fetch_callback(model):
+    """A pool that holds every routed expert serves the step on device:
+    the fetch seam never runs, and the logits are bit-equal."""
+    cfg, params = model
+    tokens = jnp.asarray([[7], [11]], jnp.int32)
+    _, _, _, routed = _fetch_step(cfg, params, tokens)
+    resident = np.zeros((routed.shape[0], cfg.moe.n_routed), bool)
+    for l, r in enumerate(routed):
+        resident[l, r] = True
+    lg_ref, lg_slot, store, _ = _fetch_step(cfg, params, tokens, resident)
+    st = store.stats()
+    assert st["fallback_fetches"] == st["fallback_rows"] == 0
+    assert st["fetch_bytes"] == 0 and st["fetch_s"] == 0.0
+    np.testing.assert_array_equal(lg_ref, lg_slot)
+
+
+def test_fetch_callback_returns_views_of_the_host_store(model):
+    """The decode miss callback hands the runtime the host store's own
+    (contiguous) expert blocks: no host copy."""
+    cfg, params = model
+    store = ExpertStore(params, cfg, n_slots=4)
+    out = store.fetch_weights_cb(np.int32(1), np.int32(5), np.int32(2))
+    for w, k in zip(out, ("gate", "up", "down")):
+        assert np.shares_memory(w, store.host[k])
+        assert w.flags["C_CONTIGUOUS"]
+        np.testing.assert_array_equal(w, store.host[k][1, 5])
+    st = store.stats()
+    assert st["fallback_fetches"] == 1 and st["fallback_rows"] == 2
+    assert st["fetch_bytes"] == store.expert_bytes
 
 
 def test_bad_fallback_rejected(model):

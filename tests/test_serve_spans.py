@@ -92,11 +92,14 @@ def test_fetch_spans_carry_layer_and_bytes_inside_a_step(traced_run):
     st = srv.store.stats()
     assert fetch and st["fetch_s"] > 0
     assert {s[4]["layer"] for s in fetch} <= set(range(srv.store.n_layers))
-    # decode calls return T·K rows of expert_bytes each; one span a call
-    rows = srv.batch * srv.cfg.moe.top_k
-    assert all(s[4]["bytes"] == rows * srv.store.expert_bytes
+    # one call, and one span, per distinct missing expert of a layer:
+    # each returns that expert's expert_bytes and serves >= 1 miss row
+    E = srv.cfg.moe.n_routed
+    assert all(s[4]["bytes"] == srv.store.expert_bytes for s in fetch)
+    assert all(0 <= s[4]["expert"] < E and s[4]["miss_rows"] >= 1
                for s in fetch)
     assert st["fetch_bytes"] == sum(s[4]["bytes"] for s in fetch)
+    assert st["fallback_fetches"] == len(fetch)
     assert sum(s[4]["miss_rows"] for s in fetch) == st["fallback_rows"]
     # the callback thread's span lies inside the step that dispatched it
     assert all(any(_inside(f, s) for s in steps) for f in fetch)
