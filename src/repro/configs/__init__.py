@@ -72,6 +72,7 @@ def make_smoke(cfg: ModelConfig) -> ModelConfig:
             mla = MLAConfig(kv_lora_rank=64, q_lora_rank=a.mla.q_lora_rank and 32,
                             qk_nope_head_dim=32, qk_rope_head_dim=16,
                             v_head_dim=32)
+        # replace() keeps the rest, the rope scaling (YaRN) among it
         kw["attn"] = dataclasses.replace(
             a, n_heads=n_heads, n_kv_heads=n_kv,
             head_dim=min(a.head_dim or d_model // n_heads, 64) or 0,

@@ -168,6 +168,10 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
     C, f = C_pad, f_pad
     bc = _block_size(C, block_c, sub)
     bf = _block_size(f, block_f, sub)
+    if bf % 128 and f % 128 == 0:
+        # bf is the lane dim of the (d, bf) weight blocks: a multiple of
+        # 128 on TPU (DeepSeek-V2-Lite's f = 1408 = 11 x 128 gives 128)
+        bf = _block_size(f, block_f, 128)
     grid = (E, C // bc, f // bf)
     out_shape = jax.ShapeDtypeStruct((E, C, d), jnp.float32)
     params = pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit_bytes(

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
-from .layers import apply_rope, dense_init, rms_norm_vec, rope_table
+from .layers import (apply_rope, dense_init, rms_norm_vec, rope_table,
+                     score_divisor)
 
 NEG_INF = -1e30
 
@@ -284,12 +285,12 @@ def gqa_attention(params, x, cfg: ModelConfig, *, kind: str,
         k = rms_norm_vec(params["k_norm"], k)
     # (Bm, S, D/2) tables: Bm=1 broadcasts for shared positions, Bm=B gives
     # every slot its own rotary phase (continuous batching)
-    cos, sin = rope_table(_pos_rows(positions), hd, a.rope_theta)
+    cos, sin = rope_table(_pos_rows(positions), hd, a.rope_theta, a.yarn)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
     window = a.sliding_window if kind == "attn_local" else 0
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / score_divisor(a.yarn, hd)
     if cache is None:
         y = _mha(q, k, v, positions, positions, causal=causal,
                  window=window, softcap=a.attn_softcap, scale=scale)
@@ -330,7 +331,7 @@ def mla_attention(params, x, cfg: ModelConfig, *, positions, cache=None):
     ckv = rms_norm_vec(params["ckv_norm"], dkv[..., :R])       # (B,S,R)
     kpe = dkv[..., R:][:, :, None, :]                          # (B,S,1,rp)
 
-    cos, sin = rope_table(_pos_rows(positions), rp, a.rope_theta)
+    cos, sin = rope_table(_pos_rows(positions), rp, a.rope_theta, a.yarn)
     q_pe = apply_rope(q_pe, cos, sin)
     kpe = apply_rope(kpe, cos, sin)
 
@@ -372,7 +373,7 @@ def mla_attention(params, x, cfg: ModelConfig, *, positions, cache=None):
         s = s + jnp.einsum("bqhp,bsp->bhqs", q_pe.astype(f32),
                            kpe_all[:, :, 0].astype(f32)
                            if kpe_all.ndim == 4 else kpe_all.astype(f32))
-        s = s / np.sqrt(nope + rp)
+        s = s / score_divisor(a.yarn, nope + rp)
         valid = _attn_mask(positions, k_pos, causal=True, window=0)
         s = jnp.where(valid[:, None], s, NEG_INF)   # (Bm,1,Sq,S) vs (B,H,Sq,S)
         p = jax.nn.softmax(s, axis=-1)                         # (B,H,1,S)
@@ -390,7 +391,7 @@ def mla_attention(params, x, cfg: ModelConfig, *, positions, cache=None):
         kpe_all, (B, Sk, H, rp)).astype(k_nope.dtype)], axis=-1)
     q_full = jnp.concatenate([q_nope, q_pe], axis=-1)
     y = _mha(q_full, k, vv, positions, k_pos, causal=True, window=0,
-             softcap=0.0, scale=1.0 / np.sqrt(nope + rp))
+             softcap=0.0, scale=1.0 / score_divisor(a.yarn, nope + rp))
     return y @ params["wo"], cache
 
 

@@ -38,6 +38,27 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) in DeepSeek-V2's form, the
+    ``rope_scaling`` group of its ``config.json`` (``type: yarn``).
+
+    Pair i of the rotary frequencies theta^(-2i/d) keeps its frequency
+    below the correction range [low, high], is divided by ``factor``
+    above it, with a linear ramp between; low and high are the pairs
+    that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings`` positions.  cos/sin are scaled
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim) and the
+    attention softmax scale by mscale(factor, mscale_all_dim)^2."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     n_heads: int = 8
     n_kv_heads: int = 8
@@ -48,6 +69,7 @@ class AttentionConfig:
     sliding_window: int = 0       # >0: window size for *local* layers
     local_global_period: int = 0  # gemma2: 2 -> alternate (local, global)
     mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None   # None: plain rope, 1/sqrt(d) scale
 
     def head_dim_of(self, d_model: int) -> int:
         if self.mla is not None:

@@ -8,6 +8,8 @@ matters for stability (norms, softmax) and ``cfg.dtype`` elsewhere.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -60,11 +62,55 @@ def rms_norm_vec(w, x, eps: float = 1e-6):
 # Rotary position embeddings
 # --------------------------------------------------------------------------
 
-def rope_table(positions, head_dim: int, theta: float):
-    """cos/sin tables for given integer positions -> (..., head_dim//2)."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 1 + 0.1 * mscale * ln(factor)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn, head_dim: int, theta: float):
+    """(low, high): the frequency pairs that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context, clipped to the pairs."""
+    def pair(turns):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(pair(yarn.beta_fast)), 0),
+            min(math.ceil(pair(yarn.beta_slow)), head_dim - 1))
+
+
+def yarn_inv_freq(yarn, head_dim: int, theta: float) -> np.ndarray:
+    """Per-pair rotary frequencies under YaRN: unscaled below the
+    correction range, divided by ``factor`` above it, a linear ramp
+    between."""
+    extra = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    low, high = yarn_correction_range(yarn, head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def score_divisor(yarn, qk_dim: int) -> float:
+    """What attention scores are divided by: sqrt(qk_dim), over YaRN's
+    mscale(factor, mscale_all_dim)^2 where the model has it."""
+    if yarn is None:
+        return np.sqrt(qk_dim)
+    return np.sqrt(qk_dim) / yarn_mscale(yarn.factor,
+                                         yarn.mscale_all_dim) ** 2
+
+
+def rope_table(positions, head_dim: int, theta: float, yarn=None):
+    """cos/sin tables for given integer positions -> (..., head_dim//2);
+    ``yarn`` (a ``YarnConfig``) rescales the frequencies and magnitudes."""
+    if yarn is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    else:
+        inv_freq = yarn_inv_freq(yarn, head_dim, theta)
     ang = positions[..., None].astype(jnp.float32) * inv_freq[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    mag = (yarn_mscale(yarn.factor, yarn.mscale)
+           / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return jnp.cos(ang) * mag, jnp.sin(ang) * mag
 
 
 def apply_rope(x, cos, sin):

@@ -373,6 +373,7 @@ class ExpertStore:
         # since the last drain and resets that baseline.
         self._tel_lock = threading.Lock()
         self._tel = {
+            "routed_rows": 0,        # decode (token, k) slots routed here
             "fallback_rows": 0,      # (token, k) slots served by misses
             "fallback_fetches": 0,   # experts demand-fetched
             "h2d_rows": 0,           # experts streamed into the pool
@@ -467,6 +468,12 @@ class ExpertStore:
     def _bump(self, name: str, v=1):
         with self._tel_lock:
             self._tel[name] += v
+
+    def book_routed(self, live: int):
+        """Book one decode step's routed (token, k) rows: ``live`` slots
+        times top-k in each of the store's layers (the denominator of the
+        pool's hit share; ``fallback_rows`` counts the ones that missed)."""
+        self._bump("routed_rows", live * self.cfg.moe.top_k * self.n_layers)
 
     def stats(self) -> dict:
         """Monotonic counter totals (numeric only — benchmarks diff

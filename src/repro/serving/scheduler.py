@@ -407,6 +407,7 @@ class ContinuousBatchServer:
                     state, logits, tel = self._decode(self.params, state,
                                                       self.res_vecs)
                     if self.store is not None:
+                        self.store.book_routed(len(busy))
                         self.store.post_dispatch(self.offload, pool_target)
                 with TraceAnnotation("dali:serve.tokens", step=step):
                     toks = np.asarray(state["tokens"])[:, 0]
@@ -575,6 +576,7 @@ class BatchServer:
             state, logits, tel = self._decode(self.params, state,
                                               self.res_vecs)
             if self.store is not None:
+                self.store.book_routed(emitted)
                 self.store.post_dispatch(self.offload, pool_target)
             toks = np.asarray(state["tokens"])[:, 0]
             t_step = time.perf_counter()

@@ -52,8 +52,10 @@ def _capacity(arch, tokens):
     return expert_capacity(get_config(arch).moe, tokens)
 
 
-# (arch, prefill tokens, expert-parallel devices for the grouped variant)
-WIDTHS = [("mixtral-8x7b", 128, 4), ("qwen3-30b-a3b", 512, 4)]
+# (arch, prefill tokens, expert-parallel devices for the grouped variant);
+# DeepSeek-V2-Lite's f = 1408 = 11 x 128 leaves block_f = 128 alone
+WIDTHS = [("mixtral-8x7b", 128, 4), ("qwen3-30b-a3b", 512, 4),
+          ("deepseek-v2-lite-16b", 512, 4)]
 
 
 @pytest.mark.parametrize("variant", ["dense", "ragged", "grouped"])
