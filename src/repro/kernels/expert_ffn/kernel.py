@@ -13,6 +13,7 @@ accumulates partial down-projections in VMEM across the f sweep:
   Wd block    (bf, d)   — streamed per (e, fi)          ~ bf*d*2
   out block   (bc, d)   — f32 accumulator, revisited    ~ bc*d*4
   counts      (E,)      — scalar-prefetched to SMEM (ragged variant)
+  layer       (1,)      — scalar-prefetched to SMEM (stacked variant)
 
 Block sizes default to MXU-friendly multiples of 128 and are clamped to
 the problem size.  All matmuls accumulate in f32
@@ -40,7 +41,13 @@ prefetched group→expert map, whose ids drive the WEIGHT block index maps
 (no gathered/replicated weight copies).  This is the expert-parallel
 entry: each received bucket (source device, local expert) is one group
 (models/moe_ep.py), so blocks a remote device sent empty skip their MXU
-work exactly like local empty buckets."""
+work exactly like local empty buckets.
+
+The stacked variant (``layer``) reads one layer's weights in place from
+the model's (L, E, d, f) / (L, E, f, d) scan stacks: a scalar-prefetched
+layer index leads the weight index maps, so the layer scan hands the
+kernel the whole stacks instead of writing a (E, d, f) slice of each per
+call (models/model.py)."""
 from __future__ import annotations
 
 import functools
@@ -53,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 _ACTS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}
 
 
-def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act, n_fi):
+def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act):
     fi = pl.program_id(2)
 
     @pl.when(fi == 0)
@@ -93,15 +100,6 @@ def _kernel_ragged(counts_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *,
                             preferred_element_type=jnp.float32)
 
 
-def _kernel_grouped(counts_ref, eids_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                    o_ref, *, act, bc):
-    # identical compute to _kernel_ragged; eids_ref is consumed by the
-    # weight BlockSpec index maps, not the body
-    del eids_ref
-    _kernel_ragged(counts_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-                   act=act, bc=bc)
-
-
 def _sublane(dtype) -> int:
     """Minimum second-minor tile dim per dtype (TPU layout constraint)."""
     return {jnp.dtype(jnp.bfloat16): 16, jnp.dtype(jnp.int8): 32}.get(
@@ -138,7 +136,7 @@ def _block_size(n: int, target: int, unit: int = 1) -> int:
                                              "interpret"))
 def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
                block_c: int = 128, block_f: int = 512,
-               interpret: bool = False, expert_ids=None):
+               interpret: bool = False, expert_ids=None, layer=None):
     """xe (E, C, d); w_gate/w_up (E, d, f); w_down (E, f, d) -> (E, C, d).
 
     With ``counts`` (E,) int32 — tokens actually packed per expert — the
@@ -147,9 +145,21 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
 
     With ``expert_ids`` (G,) int32 as well, xe is (G, C, d) row groups and
     group g computes against weight set expert_ids[g] (expert-parallel
-    receive buckets: one group per (source device, local expert))."""
+    receive buckets: one group per (source device, local expert)).
+
+    With ``layer`` (an int32 scalar or (1,)), the weights are whole
+    per-layer stacks — w_gate/w_up (L, E, d, f), w_down (L, E, f, d) —
+    and the kernel reads layer ``layer``'s blocks where they lie: the
+    index rides as a scalar-prefetch operand into the weight index maps,
+    so no (E, d, f) slice of the stack is ever materialised.  Each block
+    computes exactly what the call on ``w[layer]`` computes.  The stacks
+    are never copied, so f must already be a multiple of the sublane
+    tile (no padding of the weights): otherwise this raises and the
+    caller slices the layer itself."""
     if expert_ids is not None and counts is None:
         raise ValueError("expert_ids requires counts (grouped ragged)")
+    if expert_ids is not None and layer is not None:
+        raise ValueError("layer and expert_ids do not combine")
     E, C, d = xe.shape
     f = w_gate.shape[-1]
     # pad the sublane-facing dims (token rows; f as Wd's row dim) to the
@@ -162,6 +172,10 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
     if C_pad != C:
         xe = jnp.pad(xe, ((0, 0), (0, C_pad - C), (0, 0)))
     if f_pad != f:
+        if layer is not None:
+            raise ValueError(f"stacked weights need f ({f}) to be a "
+                             f"multiple of the sublane tile ({sub}); pass "
+                             "the layer's slice without layer=")
         w_gate = jnp.pad(w_gate, ((0, 0), (0, 0), (0, f_pad - f)))
         w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, f_pad - f)))
         w_down = jnp.pad(w_down, ((0, 0), (0, f_pad - f), (0, 0)))
@@ -172,76 +186,51 @@ def expert_ffn(xe, w_gate, w_up, w_down, counts=None, act: str = "silu",
         # bf is the lane dim of the (d, bf) weight blocks: a multiple of
         # 128 on TPU (DeepSeek-V2-Lite's f = 1408 = 11 x 128 gives 128)
         bf = _block_size(f, block_f, 128)
-    grid = (E, C // bc, f // bf)
-    out_shape = jax.ShapeDtypeStruct((E, C, d), jnp.float32)
     params = pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit_bytes(
         bc, bf, d, jnp.dtype(xe.dtype).itemsize))
 
-    if counts is None:
-        y = pl.pallas_call(
-            functools.partial(_kernel, act=act, n_fi=f // bf),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bc, d), lambda e, ci, fi: (e, ci, 0)),
-                pl.BlockSpec((1, d, bf), lambda e, ci, fi: (e, 0, fi)),
-                pl.BlockSpec((1, d, bf), lambda e, ci, fi: (e, 0, fi)),
-                pl.BlockSpec((1, bf, d), lambda e, ci, fi: (e, fi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bc, d), lambda e, ci, fi: (e, ci, 0)),
-            out_shape=out_shape,
-            compiler_params=params,
-            interpret=interpret,
-        )(xe, w_gate, w_up, w_down)
-        return y[:, :C_in].astype(xe.dtype)
-
+    # scalar-prefetch operands ride ahead of the grid into SMEM, in this
+    # order: the layer index and the group->expert map drive the WEIGHT
+    # index maps (no gathered or sliced weight copies materialise);
+    # counts feed the ragged body's pl.when guard
+    prefetch = []
+    if layer is not None:
+        prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
+    if counts is not None:
+        prefetch.append(counts.astype(jnp.int32))
     if expert_ids is not None:
-        # grouped ragged: counts AND the group→expert map ride ahead of
-        # the grid as scalar-prefetch operands (SMEM); the map drives the
-        # weight index maps so no gathered weight copies materialise
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bc, d),
-                             lambda g, ci, fi, c, eid: (g, ci, 0)),
-                pl.BlockSpec((1, d, bf),
-                             lambda g, ci, fi, c, eid: (eid[g], 0, fi)),
-                pl.BlockSpec((1, d, bf),
-                             lambda g, ci, fi, c, eid: (eid[g], 0, fi)),
-                pl.BlockSpec((1, bf, d),
-                             lambda g, ci, fi, c, eid: (eid[g], fi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bc, d),
-                                   lambda g, ci, fi, c, eid: (g, ci, 0)),
-        )
-        y = pl.pallas_call(
-            functools.partial(_kernel_grouped, act=act, bc=bc),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=params,
-            interpret=interpret,
-        )(counts.astype(jnp.int32), expert_ids.astype(jnp.int32),
-          xe, w_gate, w_up, w_down)
-        return y[:, :C_in].astype(xe.dtype)
+        prefetch.append(expert_ids.astype(jnp.int32))
 
-    # ragged: counts ride ahead of the grid as a scalar-prefetch operand
-    # (SMEM), so the pl.when guard reads them before any block compute
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bc, d), lambda e, ci, fi, c: (e, ci, 0)),
-            pl.BlockSpec((1, d, bf), lambda e, ci, fi, c: (e, 0, fi)),
-            pl.BlockSpec((1, d, bf), lambda e, ci, fi, c: (e, 0, fi)),
-            pl.BlockSpec((1, bf, d), lambda e, ci, fi, c: (e, fi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bc, d), lambda e, ci, fi, c: (e, ci, 0)),
-    )
+    if layer is not None:                    # (L, E, ...) stacks: [layer, e]
+        lead, wblock = (lambda e, pre: (pre[0][0], e)), (None, 1)
+    elif expert_ids is not None:             # (E, ...) sets: [expert_ids[g]]
+        lead, wblock = (lambda e, pre: (pre[-1][e],)), (1,)
+    else:
+        lead, wblock = (lambda e, pre: (e,)), (1,)
+    rows = pl.BlockSpec((1, bc, d), lambda e, ci, fi, *pre: (e, ci, 0))
+    w_in = pl.BlockSpec((*wblock, d, bf),
+                        lambda e, ci, fi, *pre: (*lead(e, pre), 0, fi))
+    w_out = pl.BlockSpec((*wblock, bf, d),
+                         lambda e, ci, fi, *pre: (*lead(e, pre), fi, 0))
+
+    def body(*refs):
+        pre, blocks = refs[:len(prefetch)], refs[len(prefetch):]
+        if counts is None:
+            return _kernel(*blocks, act=act)
+        return _kernel_ragged(pre[int(layer is not None)], *blocks,
+                              act=act, bc=bc)
+
     y = pl.pallas_call(
-        functools.partial(_kernel_ragged, act=act, bc=bc),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        body,
+        name="expert_ffn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(E, C // bc, f // bf),
+            in_specs=[rows, w_in, w_in, w_out],
+            out_specs=rows,
+        ),
+        out_shape=jax.ShapeDtypeStruct((E, C, d), jnp.float32),
         compiler_params=params,
         interpret=interpret,
-    )(counts.astype(jnp.int32), xe, w_gate, w_up, w_down)
+    )(*prefetch, xe, w_gate, w_up, w_down)
     return y[:, :C_in].astype(xe.dtype)

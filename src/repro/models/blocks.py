@@ -60,7 +60,10 @@ def apply_block(params, x, cfg: ModelConfig, kinds, *, positions,
                 count_overlap: Optional[bool] = None,
                 slots=None, slot_fetch=None, slot_live=None,
                 slot_inject=None, slot_little=None,
-                slot_phase: str = "decode"):
+                slot_phase: str = "decode", expert_layer=None):
+    """``expert_layer`` (a scanned resident MoE layer's index): the MLP's
+    routed-expert gate/up/down are the whole scan stacks, and apply_moe
+    uses that layer's (models/model.py)."""
     mixer_kind, mlp_kind = kinds
     moe_info = None
     new_cache = cache
@@ -116,7 +119,8 @@ def apply_block(params, x, cfg: ModelConfig, kinds, *, positions,
                                     slot_live=slot_live,
                                     slot_inject=slot_inject,
                                     slot_little=slot_little,
-                                    slot_phase=slot_phase)
+                                    slot_phase=slot_phase,
+                                    layer=expert_layer)
         else:
             y = apply_mlp(params["mlp"], h, cfg)
             if mixer_kind == "cross":   # gated FFN on VLM cross layers

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from .blocks import apply_block, init_block, init_block_cache
 from .config import ModelConfig, scan_pattern
 from .layers import embed, init_embedding, init_norm, apply_norm, unembed
+from .moe import EXPERT_STACKS
 
 
 # --------------------------------------------------------------------------
@@ -153,6 +154,22 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
     def with_pool(s):
         return s if s is None or pool is None else dict(s, pool=pool)
 
+    # resident MoE layers (no slot view): the routed-expert stacks
+    # (n_super, E, ...) are scan CONSTANTS too, and each layer's FFN reads
+    # its own [lid] where it lies (the expert-FFN kernel takes the layer
+    # index).  Sliced through the xs, every layer would first copy its
+    # (E, d, f) weights out of the stacks, on every call
+    stacks, scan_params = [], []
+    for p, (_, mlp_kind) in enumerate(period_pat):
+        pp = params["scan"][p]
+        st = None
+        if mlp_kind == "moe" and expert_slots is None:
+            st = {k: pp["mlp"][k] for k in EXPERT_STACKS}
+            pp = dict(pp, mlp={k: v for k, v in pp["mlp"].items()
+                               if k not in EXPERT_STACKS})
+        stacks.append(st)
+        scan_params.append(pp)
+
     infos = []
     new_prefix_caches = []
     for i, kinds in enumerate(prefix_pat):
@@ -172,12 +189,15 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
         infos.append(_trim_info(info, trace))
 
     def body(x, sliced):
-        p_slices, c_slices, s_slices = sliced
+        p_slices, c_slices, s_slices, lid = sliced
         step_infos = []
         new_cs = []
         for p, kinds in enumerate(period_pat):
             c = c_slices[p] if c_slices is not None else None
-            x, c, info = apply_block(p_slices[p], x, cfg, kinds,
+            pp = p_slices[p]
+            if stacks[p] is not None:
+                pp = dict(pp, mlp=dict(pp["mlp"], **stacks[p]))
+            x, c, info = apply_block(pp, x, cfg, kinds,
                                      positions=positions, cache=c,
                                      cross_src=cross_src,
                                      moe_capacity=moe_capacity,
@@ -187,7 +207,9 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
                                      slot_live=slot_live,
                                      slot_inject=slot_inject,
                                      slot_little=slot_little,
-                                     slot_phase=slot_phase)
+                                     slot_phase=slot_phase,
+                                     expert_layer=(None if stacks[p] is None
+                                                   else lid))
             x = hint(x, "batch", "res_seq", "embed")
             new_cs.append(c)
             step_infos.append(_trim_info(info, trace))
@@ -197,7 +219,9 @@ def apply_model(params, tokens, cfg: ModelConfig, *, positions=None,
         body = jax.checkpoint(body)
 
     scan_caches = caches["scan"] if caches is not None else None
-    xs = (params["scan"], scan_caches, slots_scan)
+    lids = (jnp.arange(n_super, dtype=jnp.int32)
+            if any(st is not None for st in stacks) else None)
+    xs = (tuple(scan_params), scan_caches, slots_scan, lids)
     x, (new_scan_caches, scan_infos) = jax.lax.scan(body, x, xs)
     infos.append(scan_infos)
 

@@ -169,7 +169,19 @@ def route(params, x_flat, m: MoEConfig):
     return gates, idx, probs, logits
 
 
-def expert_ffn_dense(params, xe, cfg: ModelConfig, counts=None):
+# the routed experts' weight stacks in a MoE layer's params
+EXPERT_STACKS = ("gate", "up", "down")
+
+
+def _at_layer(params, layer):
+    """``params`` with the routed-expert (L, E, ...) stacks cut to layer
+    ``layer``'s (E, ...) weights; unchanged without a layer."""
+    if layer is None:
+        return params
+    return dict(params, **{k: params[k][layer] for k in EXPERT_STACKS})
+
+
+def expert_ffn_dense(params, xe, cfg: ModelConfig, counts=None, layer=None):
     """Batched per-expert SwiGLU: xe (E, C, d) -> (E, C, d).
 
     On TPU (single device, no active mesh) this routes through the grouped
@@ -177,12 +189,16 @@ def expert_ffn_dense(params, xe, cfg: ModelConfig, counts=None):
     ``counts`` so empty/partial capacity blocks skip their MXU work
     (skip-empty, MegaBlocks-style).  Elsewhere the jnp einsum path below
     runs — it is also the kernel's oracle.  Rows at or beyond ``counts[e]``
-    are zero on both paths (the dispatch zero-fills them)."""
+    are zero on both paths (the dispatch zero-fills them).  With ``layer``
+    the weights are (L, E, ...) stacks: the kernel reads that layer's in
+    place, the einsum path slices them."""
     from repro.launch.sharding import active, hint
     if jax.default_backend() == "tpu" and active()["mesh"] is None:
         from repro.kernels.expert_ffn.ops import expert_ffn_op
         return expert_ffn_op(xe, params["gate"], params["up"],
-                             params["down"], act=cfg.act, counts=counts)
+                             params["down"], act=cfg.act, counts=counts,
+                             layer=layer)
+    params = _at_layer(params, layer)
     act = _ACTS[cfg.act]
     h = act(jnp.einsum("ecd,edf->ecf", xe, params["gate"])) \
         * jnp.einsum("ecd,edf->ecf", xe, params["up"])
@@ -219,7 +235,8 @@ def _combine_topk(ys, gates):
                    * gates.astype(ys.dtype)[..., None], axis=1)
 
 
-def grouped_expert_ffn(params, xf, idx, gates, cfg: ModelConfig):
+def grouped_expert_ffn(params, xf, idx, gates, cfg: ModelConfig,
+                       layer=None):
     """Sparse decode fast path: per-(token, k) gathered-weight SwiGLU.
 
     Gathers the T·K activated experts' weight slices and contracts each
@@ -227,10 +244,11 @@ def grouped_expert_ffn(params, xf, idx, gates, cfg: ModelConfig):
     zero-bucket compute, and no drops by construction (every slot keeps
     its expert).  Cost scales with the actual activated workload T·K
     instead of the dense E·C sweep.  xf (T, d), idx/gates (T, K) ->
-    combined output (T, d)."""
+    combined output (T, d).  With ``layer`` the weights are (L, E, ...)
+    stacks, gathered at [layer, e]."""
     flat_e = idx.reshape(-1)                       # (T*K,) activated experts
-    ys = _grouped_ffn_rows(xf, params["gate"][flat_e], params["up"][flat_e],
-                           params["down"][flat_e], cfg)
+    at = flat_e if layer is None else (layer, flat_e)
+    ys = _grouped_ffn_rows(xf, *(params[k][at] for k in EXPERT_STACKS), cfg)
     return _combine_topk(ys, gates)
 
 
@@ -543,7 +561,7 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
               placement=None, demand_view: bool = False,
               slots=None, slot_fetch=None, slot_live=None,
               slot_inject=None, slot_little=None,
-              slot_phase: str = "decode"):
+              slot_phase: str = "decode", layer=None):
     """Returns (y, info) where info carries DALI's routing observables.
 
     ``valid`` (T,) bool marks real tokens (None = all real): padded tokens
@@ -572,7 +590,10 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
     rule — prefill-sized inputs run the dense capacity sweep against
     full (E, ...) stacks assembled from the pool plus wave-streamed
     misses (``slot_expert_stacks``, DESIGN.md §11), and may chunk via
-    the scan below."""
+    the scan below.  ``layer`` (a scanned resident layer's index) means
+    ``params``' gate/up/down are the whole (L, E, ...) scan stacks: the
+    expert-FFN kernel reads layer ``layer``'s in place, every other path
+    takes ``stack[layer]`` (models/model.py)."""
     from repro.launch.sharding import hint
     from repro.models.moe_ep import apply_moe_ep, ep_applicable
     if force_path not in (None, "dense", "sparse"):
@@ -585,7 +606,8 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
             and ep_applicable(cfg, B, S)):
         # production path under an active mesh: shard_map expert-parallel
         # all-to-all dispatch (see moe_ep.py / EXPERIMENTS.md §Perf)
-        return apply_moe_ep(params, x, cfg, capacity=capacity,
+        return apply_moe_ep(_at_layer(params, layer), x, cfg,
+                            capacity=capacity,
                             force_exchange=force_exchange,
                             count_overlap=count_overlap,
                             placement=placement,
@@ -626,7 +648,7 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
                                 slots=slots, slot_fetch=slot_fetch,
                                 slot_inject=slot_inject,
                                 slot_little=slot_little,
-                                slot_phase=slot_phase)
+                                slot_phase=slot_phase, layer=layer)
             return None, (y, info)
 
         _, (yc, infos) = jax.lax.scan(body, None, (xc, vc))
@@ -674,7 +696,8 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
                                 live=live, slot_inject=slot_inject,
                                 slot_little=slot_little)
         else:
-            y = grouped_expert_ffn(params, xf, idx, gates, cfg)
+            y = grouped_expert_ffn(params, xf, idx, gates, cfg,
+                                   layer=layer)
         counts = _workload_counts(idx.reshape(-1), E, vrep)
         if valid is not None:
             y = jnp.where(valid[:, None], y, 0)
@@ -713,7 +736,8 @@ def apply_moe(params, x, cfg: ModelConfig, *, capacity: Optional[int] = None,
                     lambda a: jnp.zeros(hshape.shape, hshape.dtype),
                     (slots["lid"], xf, idx.reshape(-1), host_hit))
         else:
-            ye = expert_ffn_dense(params, xe, cfg, counts=counts) # (E,C,d)
+            ye = expert_ffn_dense(params, xe, cfg, counts=counts,
+                                  layer=layer)                    # (E,C,d)
         ye = hint(ye, "experts", "cap", "embed")
 
         # gather results back in sorted-slot order, zero dropped/invalid

@@ -5,6 +5,7 @@ tests via hypothesis)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from _hypothesis_compat import given, settings, st
 from repro.configs import get_config
@@ -167,3 +168,84 @@ def test_jamba_pattern_ratios():
     moe = sum(1 for _, ml in pat if ml == "moe")
     assert attn * 7 == mamba            # 1:7 interleave
     assert moe == cfg.n_layers // 2     # MoE every other layer
+
+
+# --------------------------------------------------------------------------
+# resident expert stacks: scan constants read in place by the kernel
+# --------------------------------------------------------------------------
+
+def _resident_model(arch, n_super=3):
+    from repro.configs import make_smoke
+    from repro.models.model import init_model
+    cfg = make_smoke(get_config(arch))
+    prefix, period, _ = scan_pattern(cfg)
+    cfg = cfg.replace(n_layers=len(prefix) + n_super * len(period))
+    return cfg, init_model(jax.random.PRNGKey(0), cfg)
+
+
+def _kernel_logits(arch, sliced):
+    """Prefill then one decode step of ``apply_model`` with the MoE layers
+    on the expert-FFN kernel (interpret mode, as the TPU branch takes it).
+    ``sliced`` hands the kernel each layer's (E, ...) slice, as the layer
+    scan did when it carried the stacks in its xs; otherwise the kernel
+    reads the whole stacks at the layer index."""
+    from repro.kernels.expert_ffn import ops as ffn_ops
+    from repro.models.model import apply_model, init_caches
+    cfg, params = _resident_model(arch)
+    orig = ffn_ops.expert_ffn_op
+    layered = []
+
+    def op(xe, wg, wu, wd, layer=None, **kw):
+        if sliced and layer is not None:
+            wg, wu, wd = wg[layer], wu[layer], wd[layer]
+            layer = None
+        layered.append(layer is not None)
+        return orig(xe, wg, wu, wd, layer=layer, interpret=True, **kw)
+
+    B, S = 3, 8
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)
+    caches = init_caches(cfg, B, S + 4, dtype="float32")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(ffn_ops, "expert_ffn_op", op)
+        pre, caches, _ = apply_model(
+            params, toks, cfg, positions=jnp.arange(S, dtype=jnp.int32),
+            caches=caches)
+        nxt = jnp.argmax(pre[:, -1:], -1).astype(jnp.int32)
+        dec, caches, _ = apply_model(
+            params, nxt, cfg, positions=jnp.full((B, 1), S, jnp.int32),
+            caches=caches)
+    return pre, dec, caches, layered
+
+
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "deepseek-v2-lite-16b"])
+def test_stacked_expert_kernel_logits_bit_identical(arch):
+    pre, dec, caches, layered = _kernel_logits(arch, False)
+    pre_s, dec_s, caches_s, layered_s = _kernel_logits(arch, True)
+    # every scanned MoE layer took the stacked path (prefill and decode)
+    assert layered and all(layered) and not any(layered_s)
+    np.testing.assert_array_equal(np.asarray(pre), np.asarray(pre_s))
+    np.testing.assert_array_equal(np.asarray(dec), np.asarray(dec_s))
+    for a, b in zip(jax.tree.leaves(caches), jax.tree.leaves(caches_s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_resident_expert_stacks_are_scan_constants():
+    """The layer scan takes the routed-expert stacks whole, as constants,
+    and slices nothing of them through its xs; the attention and router
+    leaves still ride the xs."""
+    from repro.models.model import apply_model
+    cfg, params = _resident_model("mixtral-8x7b")
+    toks = jnp.zeros((2, 4), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p: apply_model(p, toks, cfg)[0])(params)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    eqn = scans[0]
+    n_c, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+    consts = [v.aval.shape for v in eqn.invars[:n_c]]
+    xs = [v.aval.shape for v in eqn.invars[n_c + n_carry:]]
+    stack = params["scan"][0]["mlp"]
+    for k in ("gate", "up", "down"):
+        assert stack[k].shape in consts
+        assert stack[k].shape not in xs
+    assert stack["router"].shape in xs

@@ -92,3 +92,37 @@ def test_expert_ffn_compiles_for_v5e(one_chip, arch, tokens, tp, variant):
     assert "tpu_custom_call" in compiled.as_text()
     out = compiled.out_info
     assert out.shape == args[0].shape and out.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("batch,seq", [(24, 1), (1, 512)])
+def test_resident_moe_reads_expert_stacks_in_place(one_chip, monkeypatch,
+                                                   batch, seq):
+    """A scanned resident MoE at Mixtral widths (4 layers; a 24-row
+    decode, a 512-token prefill) hands the expert-FFN kernel the whole
+    (L, E, ...) stacks: no (E, d, f) slice of them is written, and the
+    kernel keeps the name the trace reader matches (bench/readers.py)."""
+    import dataclasses
+    import re
+
+    from repro.models.model import apply_model, init_model
+    cfg = get_config("mixtral-8x7b")
+    cfg = cfg.replace(n_layers=4, moe=dataclasses.replace(
+        cfg.moe, capacity_factor=0.0))
+    shapes = jax.eval_shape(lambda k: init_model(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    toks = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    # the model's kernel branch asks the default backend, which here is
+    # the CPU: the compile itself targets the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = jax.jit(lambda p, t: apply_model(
+        p, t, cfg, last_logit_only=True)[0]).lower(params, toks) \
+        .compile().as_text()
+    d, f, E, _ = _widths("mixtral-8x7b")
+    for sliced in (f"bf16[{E},{d},{f}]", f"bf16[{E},{f},{d}]"):
+        assert sliced not in hlo
+    kernels = re.findall(r"%(\S+) = [^\n]*custom_call_target="
+                         r"\"tpu_custom_call\"", hlo)
+    assert kernels
+    assert all(re.fullmatch(r"expert_ffn(\.\d+)?", k) for k in kernels)
